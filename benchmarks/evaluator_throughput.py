@@ -34,8 +34,9 @@ CI baseline).  `--check <baseline.json>` exits nonzero when
   * the measured legacy->array speedup regresses to less than half the
     baseline's (machine-independent: both numbers come from one host),
   * the fused path scores below 3x the in-run gather-path `array_cps`, or
-  * the warm jax path falls behind the in-run `array_cps` (when jax
-    imports).
+  * the warm jax path falls behind the in-run `array_cps`.
+
+A failure of the jax backend fails the run.
 
 Usage:
   PYTHONPATH=src python benchmarks/evaluator_throughput.py            # full
@@ -310,27 +311,24 @@ def run_bench(app: str = "resnet", pool: int = 4096, repeats: int = 5,
         "sharded_cps": pool / t_sharded,
     }
 
-    try:
-        # cold: a fresh evaluator's first call — jit trace + compile +
-        # table upload + score (what a new (app, space) pays once)
-        jax_ev = make_ev("jax")
-        t0 = time.perf_counter()
-        jax_perf = jax_ev(warm_batch)
-        t_jax_cold = time.perf_counter() - t0
-        rel = (np.abs(jax_perf - legacy_perf)
-               / np.maximum(np.abs(legacy_perf), 1e-30))
-        results["jax_max_rel_err"] = float(rel.max())
-        # warm steady-state: the persistent jitted kernel on uncached
-        # work — time the fused scorer directly (the evaluator row cache
-        # would serve repeat calls as hits and measure the cache instead)
-        scorer = jax_ev._scorer()
-        matrix = warm_batch.matrix
-        t_jax = _best_seconds(lambda: scorer.metrics(matrix), repeats)
-        results["jax_cold_s"] = t_jax_cold
-        results["jax_cps"] = pool / t_jax
-        results["jax_speedup_vs_legacy"] = t_legacy / t_jax
-    except Exception as e:                        # jax missing / no device
-        results["jax_error"] = f"{type(e).__name__}: {e}"
+    # cold: a fresh evaluator's first call — jit trace + compile +
+    # table upload + score (what a new (app, space) pays once)
+    jax_ev = make_ev("jax")
+    t0 = time.perf_counter()
+    jax_perf = jax_ev(warm_batch)
+    t_jax_cold = time.perf_counter() - t0
+    rel = (np.abs(jax_perf - legacy_perf)
+           / np.maximum(np.abs(legacy_perf), 1e-30))
+    results["jax_max_rel_err"] = float(rel.max())
+    # warm steady-state: the persistent jitted kernel on uncached
+    # work — time the fused scorer directly (the evaluator row cache
+    # would serve repeat calls as hits and measure the cache instead)
+    scorer = jax_ev._scorer()
+    matrix = warm_batch.matrix
+    t_jax = _best_seconds(lambda: scorer.metrics(matrix), repeats)
+    results["jax_cold_s"] = t_jax_cold
+    results["jax_cps"] = pool / t_jax
+    results["jax_speedup_vs_legacy"] = t_legacy / t_jax
 
     if verbose:
         print(f"[evaluator-throughput] app={app} pool={pool}")
@@ -347,12 +345,11 @@ def run_bench(app: str = "resnet", pool: int = 4096, repeats: int = 5,
               f"(pool {results['round_pool']})")
         print(f"  sharded x{results['sharded_workers']}          : "
               f"{results['sharded_cps']:12.0f} configs/s   (bit-identical)")
-        if "jax_cps" in results:
-            print(f"  jax warm            : {results['jax_cps']:12.0f} "
-                  f"configs/s   (max rel err "
-                  f"{results['jax_max_rel_err']:.2e})")
-            print(f"  jax cold (compile)  : {results['jax_cold_s']:12.3f} s "
-                  f"first call")
+        print(f"  jax warm            : {results['jax_cps']:12.0f} "
+              f"configs/s   (max rel err "
+              f"{results['jax_max_rel_err']:.2e})")
+        print(f"  jax cold (compile)  : {results['jax_cold_s']:12.3f} s "
+              f"first call")
         print(f"  repair scalar       : "
               f"{results['repair_scalar_cps']:12.0f} configs/s")
         print(f"  repair batched      : "
@@ -426,8 +423,8 @@ def check_regression(results: dict, baseline: dict,
         comparable; --smoke keeps the baseline's pool for this reason),
       * fused_cps must be >= `fused_floor` x the in-run array_cps (the
         fused hot path earns its complexity or fails loudly),
-      * warm jax_cps must be >= the in-run array_cps when jax imports
-        (the accelerator backend at least matches the numpy gather path).
+      * warm jax_cps must be >= the in-run array_cps (the accelerator
+        backend at least matches the numpy gather path).
     """
     # -- in-run gates (no baseline dependence) --
     array_cps = float(results.get("array_cps", 0.0))
@@ -438,17 +435,13 @@ def check_regression(results: dict, baseline: dict,
         raise SystemExit(2)
     print(f"[check] ok: fused {fused_cps / max(array_cps, 1e-30):.1f}x "
           f"array (gate: >= {fused_floor:g}x)")
-    if "jax_cps" in results:
-        jax_cps = float(results["jax_cps"])
-        if jax_cps < array_cps:
-            print(f"[check] REGRESSION: warm jax {jax_cps:.0f} configs/s < "
-                  f"array {array_cps:.0f} configs/s")
-            raise SystemExit(2)
-        print(f"[check] ok: warm jax {jax_cps / max(array_cps, 1e-30):.1f}x "
-              f"array (gate: >= 1x)")
-    else:
-        print(f"[check] jax gate skipped "
-              f"({results.get('jax_error', 'no jax_cps in results')})")
+    jax_cps = float(results["jax_cps"])
+    if jax_cps < array_cps:
+        print(f"[check] REGRESSION: warm jax {jax_cps:.0f} configs/s < "
+              f"array {array_cps:.0f} configs/s")
+        raise SystemExit(2)
+    print(f"[check] ok: warm jax {jax_cps / max(array_cps, 1e-30):.1f}x "
+          f"array (gate: >= 1x)")
     # -- baseline gate --
     base_speedup = float(baseline.get("speedup", 0.0))
     if int(results.get("pool", 0)) != int(baseline.get("pool", 0)):

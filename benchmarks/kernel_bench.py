@@ -20,7 +20,6 @@ import numpy as np
 
 from repro.core.kernel_tune import tile_cost, TileConfig, tune_matmul_tiles
 from repro.kernels import ops
-from repro.kernels.costmodel import gather_rows
 
 
 def _time(fn, *args, n=3, **kw):
@@ -60,19 +59,6 @@ def run(verbose: bool = True) -> list:
     rows.append(("rglru_scan_interp_s512", us,
                  "log_step_doubling=7_steps_per_128tile"))
 
-    # cost-model gather-reduce: the [C] -> [C, O] op-table contraction of
-    # the fused evaluation hot path (tiled one-hot gather, exact for int64)
-    with jax.experimental.enable_x64():
-        tbl = jnp.asarray(
-            np.random.default_rng(0).integers(-2**40, 2**40, (512, 16)))
-        cidx = jnp.asarray(
-            np.random.default_rng(1).integers(0, 512, 4096))
-        us = _time(gather_rows, tbl, cidx, interpret=True)
-        got = np.asarray(gather_rows(tbl, cidx, interpret=True))
-        np.testing.assert_array_equal(got, np.asarray(tbl)[np.asarray(cidx)])
-    rows.append(("costmodel_gather_interp_4096x512x16", us,
-                 "one_hot_reduce_exact_int64"))
-
     if verbose:
         print("name,us_per_call,derived")
         for r in rows:
@@ -104,20 +90,8 @@ def run_smoke(verbose: bool = True) -> None:
     out = ops.rglru_scan(a, b, bs=128, bw=256, interpret=True)
     assert np.isfinite(np.asarray(out)).all()
 
-    with jax.experimental.enable_x64():
-        rng = np.random.default_rng(0)
-        tbl = jnp.asarray(rng.integers(-2**40, 2**40, (96, 7)))
-        cidx = jnp.asarray(rng.integers(0, 96, 300))
-        got = np.asarray(gather_rows(tbl, cidx, interpret=True))
-        np.testing.assert_array_equal(got, np.asarray(tbl)[np.asarray(cidx)])
-        ftbl = jnp.asarray(rng.random((96, 7)) * 1e9)
-        got = np.asarray(gather_rows(ftbl, cidx, interpret=True))
-        np.testing.assert_array_equal(got,
-                                      np.asarray(ftbl)[np.asarray(cidx)])
-
     if verbose:
-        print("[kernel-smoke] matmul, flash_attention, rglru_scan, "
-              "costmodel gather_rows: OK")
+        print("[kernel-smoke] matmul, flash_attention, rglru_scan: OK")
 
 
 if __name__ == "__main__":

@@ -1530,18 +1530,13 @@ def _evaluate_stream_many_jax(
     peak_input_bits: int = 0,
     with_parts: bool = True,
 ) -> Tuple[np.ndarray, np.ndarray, Optional[Dict[str, np.ndarray]]]:
-    try:
-        import jax
-    except Exception as e:                      # pragma: no cover
-        raise RuntimeError(
-            "evaluate_stream_many(backend='jax') requires jax; fall back to "
-            "backend='numpy'") from e
+    import jax
     batch = ConfigBatch.from_configs(configs)
     max_batch = int(stream.batch.max()) if len(stream) else 1
     peak_input_scaled = int(peak_input_bits) * max_batch
     # x64 keeps the int64/float64 semantics of the numpy reference (the MAC
     # and traffic counts overflow int32 on real layers)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         kern = _jax_broadcast_kernel(int(hw.bit_width))
         out = kern(batch.matrix, stream.field_matrix,
                    int(peak_weight_bits), peak_input_scaled)

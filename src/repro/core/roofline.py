@@ -131,8 +131,6 @@ class RooflineReport:
 def measure_compiled(compiled) -> Tuple[float, float, CollectiveStats, float]:
     """(flops, hbm_bytes, collective stats, peak_bytes) of one executable."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: list of per-device dicts
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     hbm_bytes = float(ca.get("bytes accessed", 0.0))
     stats = parse_collective_bytes(compiled.as_text())

@@ -164,13 +164,10 @@ class Evaluator:
                 not FusedStreamScorer.supports(self.stream):
             self._fused = None
         elif self.backend == "jax":
-            try:
-                from repro.kernels.costmodel import FusedJaxScorer
-                self._fused = FusedJaxScorer(
-                    self.stream, self.hw, self.peak_weight_bits,
-                    self.peak_input_bits, domains=self.domains)
-            except ImportError:          # no jax: fall back to reference
-                self._fused = None
+            from repro.kernels.costmodel import FusedJaxScorer
+            self._fused = FusedJaxScorer(
+                self.stream, self.hw, self.peak_weight_bits,
+                self.peak_input_bits, domains=self.domains)
         else:
             self._fused = FusedStreamScorer(
                 self.stream, self.hw, self.peak_weight_bits,

@@ -35,6 +35,7 @@ studies.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 from pathlib import Path
 from typing import List, Optional, Tuple
@@ -43,6 +44,28 @@ from repro.dse.objectives import OBJECTIVES
 from repro.dse.study import SearchBudget, Study, StudyResult
 
 DEFAULT_OUT = Path("experiments") / "dse_study.json"
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def compile_cache_dir() -> Optional[Path]:
+    """Where an entry point keeps JAX's persistent compilation cache.
+
+    None when `JAX_COMPILATION_CACHE_DIR` is set (JAX reads it itself and
+    no other directory is configured); otherwise the fixed
+    `<checkout>/.jax_cache`.  The path is part of each entry's key, so it
+    never varies by run, process or time."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    return CHECKOUT / ".jax_cache"
+
+
+def configure_compile_cache() -> None:
+    """Turn on the persistent compilation cache at `compile_cache_dir()`.
+    Called by entry points only, never at import time."""
+    path = compile_cache_dir()
+    if path is not None:
+        import jax
+        jax.config.update("jax_compilation_cache_dir", str(path))
 
 
 def _parse_engine_kwargs(pairs: List[str]) -> dict:
@@ -278,6 +301,7 @@ def _print_metrics(summary: dict) -> None:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    configure_compile_cache()
     study, args = study_from_cli(argv)
 
     from repro import obs

@@ -352,6 +352,24 @@ def _cross_eval_task(payload: Dict[str, Any]) -> np.ndarray:
     return out
 
 
+def require_one_device_process(backend: str, workers: int) -> None:
+    """Refuse a worker pool whose shards would each claim the accelerator.
+
+    A chip belongs to one process at a time: with `backend="jax"` on a
+    non-CPU platform every spawned shard would build its own device scorer
+    and fight over it.  Raises before any pool is spawned; the CPU backend
+    keeps its pool."""
+    if backend != "jax" or workers <= 1:
+        return
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise ValueError(
+            f"backend='jax' on {platform!r} scores in one process: "
+            f"workers={workers} would spawn processes that each claim the "
+            f"device; use workers=1")
+
+
 def shard_rows(n: int, shards: int) -> List[np.ndarray]:
     """Contiguous row-index shards covering range(n) (order-preserving, so
     concatenating shard outputs reproduces the unsharded row order)."""
@@ -365,6 +383,7 @@ def score_population_sharded(params: EvalParams, batch: ConfigBatch,
     """Score a population with each shard on its own worker-side evaluator
     shard; ordered concatenation makes the result bit-identical to one
     unsharded evaluator call (the cost model is row-wise independent)."""
+    require_one_device_process(params.backend, executor.workers)
     shards = shard_rows(len(batch), executor.workers)
     payloads = [{"params": params, "batch": batch.take(rows)}
                 for rows in shards]

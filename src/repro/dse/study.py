@@ -67,7 +67,7 @@ from repro.dse.objectives import (GeomeanAcrossApps, MaxPerf, Objective,
 from repro.dse.parallel import (EvalParams, ParallelExecutor,
                                 canonical_front_indices, _cross_eval_task,
                                 _search_app_task, merge_pareto_fronts,
-                                shard_rows)
+                                require_one_device_process, shard_rows)
 
 __all__ = ["SearchBudget", "Study", "StudyResult", "FrontPoint"]
 
@@ -294,6 +294,9 @@ class Study:
         # every result stay byte-identical across worker counts)
         self.workers = max(1, int(workers))
         self.executor = executor
+        require_one_device_process(
+            backend, executor.workers if executor is not None
+            else self.workers)
         #: columns below this count keep the cross-eval stage serial (the
         #: fan-out only pays for itself on big candidate sets); tests drop
         #: it to force the sharded path

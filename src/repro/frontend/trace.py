@@ -44,8 +44,9 @@ __all__ = ["trace_to_graph", "trace_jaxpr", "GraphTracer",
 # matching the BITS=8 convention of the hand-built graphs.
 DEFAULT_BIT_WIDTH = 8
 
-# pjit-style call primitives: the sub-jaxpr is inlined 1:1.
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call")
+# call primitives (what `jax.jit` inside a traced function emits): the
+# sub-jaxpr is inlined 1:1.
+_CALL_PRIMS = ("jit",)
 _REMAT_PRIMS = ("remat2", "remat", "checkpoint")
 _CUSTOM_PRIMS = ("custom_jvp_call", "custom_vjp_call",
                  "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr")

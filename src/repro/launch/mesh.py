@@ -11,12 +11,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed in jax 0.5; 0.4.x meshes are implicitly Auto
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_mesh", "batch_axes_for"]
 
@@ -31,10 +26,8 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
             f"mesh {shape} needs {n} devices, found {len(devices)}; the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count before importing jax")
-    kwargs = {}
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices, **kwargs)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

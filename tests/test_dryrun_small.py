@@ -6,35 +6,14 @@ Runs in a subprocess because XLA_FLAGS must be set before jax initializes
 
 import json
 import os
-import re
 import subprocess
 import sys
 import textwrap
 from pathlib import Path
 
-import jax
 import pytest
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
-
-# jax API drift guard (precise, per the ROADMAP re-validation note):
-# last re-validated against jax 0.4.37 (2026-08-08, composition PR) —
-# both the train and decode dry-runs compile on the forced-host mesh and
-# report nonzero flops/hbm/collectives.  The mesh AxisType guard in launch/mesh.py covers
-# the 0.5+ Mesh signature, so the known-good window is [MIN, MAX); bump
-# MAX after re-validating on a newer jax rather than letting the test rot
-# silently.
-# tolerant parse: pre-release suffixes ("0.5.0rc1") must not turn the
-# skip guard into a collection error
-_JAX = tuple(int(re.match(r"\d+", x).group())
-             if re.match(r"\d+", x) else 0
-             for x in jax.__version__.split(".")[:3])
-_VALIDATED_MIN = (0, 4, 30)       # pjit/mesh surface the dry-run relies on
-_VALIDATED_MAX = (0, 8, 0)        # exclusive; last green: 0.4.37
-_SKIP_REASON = (f"jax {jax.__version__} outside the re-validated window "
-                f"[{'.'.join(map(str, _VALIDATED_MIN))}, "
-                f"{'.'.join(map(str, _VALIDATED_MAX))}); re-run this test "
-                "manually and bump the bounds in test_dryrun_small.py")
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -71,8 +50,6 @@ SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not (_VALIDATED_MIN <= _JAX < _VALIDATED_MAX),
-                    reason=_SKIP_REASON)
 def test_small_mesh_dryrun_end_to_end():
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC

@@ -400,3 +400,18 @@ def test_study_from_cli_explicit_flags_beat_smoke():
     # --budgets without a pareto objective is an error, not a silent drop
     with pytest.raises(ValueError, match="area_budgets"):
         study_from_cli(["--apps", "resnet", "--budgets", "30000"])
+
+
+def test_compile_cache_dir_follows_env(monkeypatch):
+    """Entry points keep JAX's compile cache where JAX_COMPILATION_CACHE_DIR
+    says (configuring nothing else), or else at a fixed, git-ignored
+    `<checkout>/.jax_cache`."""
+    from pathlib import Path
+
+    from repro.dse.cli import compile_cache_dir
+    root = Path(__file__).resolve().parents[1]
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(root / "elsewhere"))
+    assert compile_cache_dir() is None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache_dir() == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()

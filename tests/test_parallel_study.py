@@ -375,3 +375,35 @@ def test_executor_pool_map_matches_serial():
     ex = ParallelExecutor(workers=2)
     assert ex.map(_double, list(range(8))) == [2 * i for i in range(8)]
     assert not ex.degraded
+
+
+# ------------------------------------------- one device process per chip
+
+def test_device_backend_refuses_worker_pool(monkeypatch):
+    """On an accelerator a chip belongs to one process: backend="jax" with
+    workers > 1 is refused before any pool is spawned, for Studies and for
+    sharded evaluator scoring; the CPU backend keeps its pool."""
+    import jax
+    from repro.dse.parallel import EvalParams, score_population_sharded
+
+    spawned = []
+    monkeypatch.setattr(ParallelExecutor, "_pool_round",
+                        lambda self, *a: spawned.append(a) or [])
+    Study(apps=["ptb"], workers=2, backend="jax")      # CPU: pool allowed
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="one process"):
+        Study(apps=["ptb"], workers=2, backend="jax")
+    with pytest.raises(ValueError, match="one process"):
+        Study(apps=["ptb"], backend="jax",
+              executor=ParallelExecutor(workers=2))
+    Study(apps=["ptb"], workers=1, backend="jax")
+    Study(apps=["ptb"], workers=2, backend="numpy")
+
+    spec = AppSpec.from_app("ptb")
+    space = default_space()
+    batch = space.decode_batch(
+        space.sample_indices(np.random.default_rng(0), 8))
+    params = EvalParams(stream=spec.stream, hw=space.hw, backend="jax")
+    with pytest.raises(ValueError, match="one process"):
+        score_population_sharded(params, batch, ParallelExecutor(workers=2))
+    assert spawned == []
