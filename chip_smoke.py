@@ -85,18 +85,28 @@ def run_study(backend: str, compiles: CompileLog):
 
 def rescore(study, app: str, batch, backend: str):
     """Raw (GOPS, area) of a `ConfigBatch` through a fresh evaluator, in
-    POOL-sized calls (one padded bucket)."""
+    POOL-sized calls (one padded bucket), and the seconds spent building
+    the scorer's programs per bucket (its `scorer.program` spans)."""
+    from repro import obs
     from repro.core.search import Evaluator
     spec = next(s for s in study.specs if s.name == app)
     ev = Evaluator.for_space(spec.stream, study.space,
                              peak_weight_bits=spec.peak_weight_bits,
                              peak_input_bits=spec.peak_input_bits,
                              backend=backend)
-    parts = [ev.score_with_area(
-        batch.take(np.arange(lo, min(lo + POOL, len(batch)))))
-        for lo in range(0, len(batch), POOL)]
+    obs.enable(trace=True, metrics=False, journal=False)
+    try:
+        parts = [ev.score_with_area(
+            batch.take(np.arange(lo, min(lo + POOL, len(batch)))))
+            for lo in range(0, len(batch), POOL)]
+        loads = collections.defaultdict(float)
+        for e in obs.tracer().export():
+            if e.get("name") == "scorer.program":
+                loads[e["args"]["bucket"]] += e["dur"] / 1e6
+    finally:
+        obs.disable(reset=True)
     return (np.concatenate([p for p, _ in parts]),
-            np.concatenate([a for _, a in parts]), ev)
+            np.concatenate([a for _, a in parts]), dict(loads))
 
 
 def main() -> int:
@@ -129,21 +139,18 @@ def main() -> int:
         if not np.array_equal(bj.matrix, bn.matrix):
             failures.append(f"{app}: the two runs scored different pools")
             continue
-        gj, aj, ev = rescore(study_j, app, bj, "jax")
+        gj, aj, loads = rescore(study_j, app, bj, "jax")
         gn, an, _ = rescore(study_n, app, bn, "numpy")
         rel = np.abs(gj - gn) / np.maximum(np.abs(gn), 1e-30)
         worst = float(rel.max()) if rel.size else 0.0
         same_area = bool(np.array_equal(aj, an))
-        scorer = ev._scorer()
-        per_bucket = {b: round(s, 3)
-                      for b, s in scorer.compile_seconds.items()}
+        per_bucket = {b: round(s, 3) for b, s in loads.items()}
         print(f"[smoke] {app}: {len(gj)} configs re-scored, "
               f"{int((gn > 0).sum())} feasible, max GOPS rel err "
               f"{worst:.3e}, area bit-identical {same_area}; best "
               f"{res_j.per_app[app]['best_perf']!r} GOPS (jax) vs "
               f"{res_n.per_app[app]['best_perf']!r} (numpy); scorer "
-              f"compiles {scorer.n_compiles}, seconds per bucket "
-              f"{per_bucket}")
+              f"program load seconds per bucket {per_bucket}")
         if not worst <= GOPS_RTOL:
             failures.append(f"{app}: GOPS rel err {worst:.3e} > {GOPS_RTOL}")
         if not same_area:

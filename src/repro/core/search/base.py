@@ -586,10 +586,12 @@ def run_search(engine: Optimizer, evaluator) -> SearchResult:
         t0 = time.perf_counter() if timed else 0.0
         with obs.span("ask_tell_round", engine=engine.name,
                       round=engine.rounds):
-            pool = engine.propose()
+            with obs.span("round.propose"):
+                pool = engine.propose()
             if pool is None or len(pool) == 0:
                 break
-            round_skipped = dedup.observe(pool)
+            with obs.span("round.dedup", n=len(pool)):
+                round_skipped = dedup.observe(pool)
             evaluator.dedup_skipped = (
                 getattr(evaluator, "dedup_skipped", 0) + round_skipped)
             scores = np.asarray(evaluator(pool), dtype=np.float64)
@@ -604,16 +606,18 @@ def run_search(engine: Optimizer, evaluator) -> SearchResult:
                 scalar = observed = scores
             pools.append(pool)
             perf.extend(scalar.tolist())
-            engine.observe(pool, observed)
+            with obs.span("round.observe", n=len(pool)):
+                engine.observe(pool, observed)
         if timed:
             obs.observe(f"round_seconds.{engine.name}",
                         time.perf_counter() - t0)
         if jrn is not None:
             jrn.emit(pool, scalar, dedup_skipped=round_skipped)
     evaluated: List[Any] = []
-    for pool in pools:
-        evaluated.extend(pool.to_configs() if hasattr(pool, "to_configs")
-                         else pool)
+    with obs.span("search.materialize", n=sum(len(p) for p in pools)):
+        for pool in pools:
+            evaluated.extend(pool.to_configs()
+                             if hasattr(pool, "to_configs") else pool)
     best = engine.best
     best_perf = float(engine.best_perf)
     if best is None and evaluated:          # engine kept no incumbent

@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.costmodel import (AccelConfig, ConfigBatch,
                                   FusedStreamScorer, HardwareConstants,
                                   OpStream, area_many, performance_gops)
@@ -182,7 +183,6 @@ class Evaluator:
         constraints applied (what `performance_gops` does), plus areas.
         Area-budget masking happens post-cache so the cached values are
         objective-independent."""
-        from repro import obs
         batch = ConfigBatch.from_configs(configs)
         with obs.span("evaluate_batch", n=len(batch),
                       backend=self.backend):
@@ -200,16 +200,17 @@ class Evaluator:
         return perf, areas
 
     def __call__(self, pool) -> np.ndarray:
-        batch = ConfigBatch.from_configs(pool)
-        perf, area = self._metrics_of(batch)
-        mask = self.feasible_mask(batch, {"perf": perf, "area": area})
-        metrics = {"perf": np.where(mask, perf, 0.0), "area": area}
-        if self.objective is None:
-            return metrics["perf"]
-        values_fn = getattr(self.objective, "values", None)
-        if values_fn is not None:            # vector objective: [N, M] rows
-            return values_fn(metrics)
-        return np.where(mask, self.objective.score(metrics), 0.0)
+        with obs.span("evaluator.call", n=len(pool)):
+            batch = ConfigBatch.from_configs(pool)
+            perf, area = self._metrics_of(batch)
+            mask = self.feasible_mask(batch, {"perf": perf, "area": area})
+            metrics = {"perf": np.where(mask, perf, 0.0), "area": area}
+            if self.objective is None:
+                return metrics["perf"]
+            values_fn = getattr(self.objective, "values", None)
+            if values_fn is not None:        # vector objective: [N, M] rows
+                return values_fn(metrics)
+            return np.where(mask, self.objective.score(metrics), 0.0)
 
     def feasible_mask(self, batch, metrics) -> np.ndarray:
         """AND of the area budget and every injected constraint."""

@@ -294,7 +294,6 @@ def _search_app_task(payload: Dict[str, Any]) -> Dict[str, Any]:
     owned = obs.begin_task(payload.get("obs"))
     prev_ctx = obs.get_context()
     obs.set_context(app=payload["name"])
-    export = None
     try:
         params: EvalParams = payload["params"]
         ev = params.build()
@@ -309,25 +308,28 @@ def _search_app_task(payload: Dict[str, Any]) -> Dict[str, Any]:
                 engine=payload["engine"],
                 engine_kwargs=payload["engine_kwargs"],
                 evaluator=ev)
+        with obs.span("search.export", n=len(res.evaluated)):
+            record = {
+                "name": payload["name"],
+                "best": res.best,
+                "best_perf": float(res.best_perf),
+                "history": list(res.history),
+                "evaluated": (ConfigBatch.from_configs(res.evaluated)
+                              if res.evaluated else None),
+                "evaluated_perf": np.asarray(res.evaluated_perf,
+                                             dtype=np.float64),
+                "evaluated_values": res.evaluated_values,
+                "rounds": int(res.rounds),
+                "engine": res.engine,
+                "cache": ev.cache_export(),
+                "stats": ev.stats(),
+            }
     finally:
         export = obs.end_task(owned)
         if not owned:
             obs.replace_context(prev_ctx)
-    return {
-        "name": payload["name"],
-        "best": res.best,
-        "best_perf": float(res.best_perf),
-        "history": list(res.history),
-        "evaluated": (ConfigBatch.from_configs(res.evaluated)
-                      if res.evaluated else None),
-        "evaluated_perf": np.asarray(res.evaluated_perf, dtype=np.float64),
-        "evaluated_values": res.evaluated_values,
-        "rounds": int(res.rounds),
-        "engine": res.engine,
-        "cache": ev.cache_export(),
-        "stats": ev.stats(),
-        "obs": export,
-    }
+    record["obs"] = export
+    return record
 
 
 def _score_shard_task(payload: Dict[str, Any]) -> np.ndarray:

@@ -677,11 +677,13 @@ class Study:
         evaluator warmed from the worker shard's raw-metric cache (the
         synthesis stages re-read raw metrics; merged keys are content-
         addressed, so values are identical to an in-process run)."""
-        ev = self._job_evaluator(j)
-        if rec.get("cache"):
-            ev.cache_merge(rec["cache"])
         batch = rec.get("evaluated")
-        evaluated = batch.to_configs() if batch is not None else []
+        with obs.span("study.rebuild",
+                      n=len(batch) if batch is not None else 0):
+            ev = self._job_evaluator(j)
+            if rec.get("cache"):
+                ev.cache_merge(rec["cache"])
+            evaluated = batch.to_configs() if batch is not None else []
         return SearchResult(
             best=rec["best"], best_perf=float(rec["best_perf"]),
             history=list(rec.get("history", [])), evaluated=evaluated,

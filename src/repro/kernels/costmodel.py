@@ -13,13 +13,19 @@ device-side in a single fused XLA program.
 Pool sizes are padded up to buckets (powers of two) so steady-state
 search rounds with ragged miss-set sizes reuse a handful of compiled
 programs instead of recompiling per shape; padded rows score as invalid
-and are sliced off.  Each bucket is compiled once, ahead of its first
-call, so `n_compiles` and `compile_seconds` count real XLA compiles.
+and are sliced off.  Each bucket's program is built once, ahead of its
+first call (an XLA compile, or a load from the persistent cache).
+
+`metrics` reports to `repro.obs`: spans `scorer.code` (LUT coding and
+padding), `scorer.program` (only where a program is built for a new
+bucket, after a table upload where its `upload` is true), `scorer.run`
+(dispatch, copies, the kernel and the blocking readback) and
+`scorer.area`; counters `scorer.rows`, `scorer.rows_padded` and
+`scorer.programs`.
 """
 
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -27,6 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.costmodel import (ConfigBatch, HardwareConstants, LoopOrder,
                                   OpStream, _FAST_FIELDS, _fused_tables_for,
                                   area_many)
@@ -66,8 +73,6 @@ class FusedJaxScorer:
         self._kern = None
         self._exe: Dict[int, object] = {}   # pool bucket -> executable
         self._built_rebuilds = -1
-        self.n_compiles = 0
-        self.compile_seconds: Dict[int, float] = {}
 
     # ---------------------------------------------------------- device prep
     def _ensure_built(self) -> None:
@@ -201,24 +206,29 @@ class FusedJaxScorer:
         if n == 0:
             z = np.zeros(0, dtype=np.float64)
             return z, z.copy()
+        m = _bucket(n)
         with jax.enable_x64(True):
-            code = self.t.codes(matrix)     # may grow/rebuild the tables
-            self._ensure_built()
-            m = _bucket(n)
-            codes = np.zeros((m, len(_FAST_FIELDS)), dtype=np.int64)
-            cols = np.zeros((m, len(_COL_FIELDS)), dtype=np.int64)
-            for j, f in enumerate(_FAST_FIELDS):
-                codes[:n, j] = code[f]
-            J = ConfigBatch._INDEX
-            for j, f in enumerate(_COL_FIELDS):
-                cols[:n, j] = matrix[:, J[f]]
-            exe = self._exe.get(m)
+            with obs.span("scorer.code", n=n, bucket=m):
+                code = self.t.codes(matrix)  # may grow/rebuild the tables
+                codes = np.zeros((m, len(_FAST_FIELDS)), dtype=np.int64)
+                cols = np.zeros((m, len(_COL_FIELDS)), dtype=np.int64)
+                for j, f in enumerate(_FAST_FIELDS):
+                    codes[:n, j] = code[f]
+                J = ConfigBatch._INDEX
+                for j, f in enumerate(_COL_FIELDS):
+                    cols[:n, j] = matrix[:, J[f]]
+            upload = self._built_rebuilds != self.t.n_rebuilds
+            exe = None if upload else self._exe.get(m)
             if exe is None:
-                t0 = time.perf_counter()
-                exe = self._exe[m] = self._kern.lower(codes, cols).compile()
-                self.compile_seconds[m] = (self.compile_seconds.get(m, 0.0)
-                                           + time.perf_counter() - t0)
-                self.n_compiles += 1
-            gops = exe(codes, cols)
-        return (np.asarray(gops)[:n].astype(np.float64),
-                area_many(ConfigBatch(matrix), self.hw))
+                with obs.span("scorer.program", bucket=m, upload=upload):
+                    self._ensure_built()
+                    exe = self._exe[m] = self._kern.lower(codes,
+                                                          cols).compile()
+                    obs.counter("scorer.programs")
+            obs.counter("scorer.rows", n)
+            obs.counter("scorer.rows_padded", m)
+            with obs.span("scorer.run", n=n, bucket=m):
+                gops = np.asarray(exe(codes, cols))
+        with obs.span("scorer.area", n=n):
+            area = area_many(ConfigBatch(matrix), self.hw)
+        return gops[:n].astype(np.float64), area

@@ -1,10 +1,11 @@
 """Counters / gauges / histograms for the DSE stack.
 
 A `Metrics` registry is a plain dict triple — no background threads, no
-dependencies.  Counters are always cheap enough to leave on (worker
-faults, retry rounds, checkpoint writes fire rarely); histogram
-observations (per-engine round latency) are gated on `enabled` so hot
-loops pay nothing when metrics are off.
+dependencies.  Every recording call (`inc`, `gauge`, `observe`) returns
+at once while the registry is disabled, so hot loops pay nothing when
+metrics are off and nothing counted before `enable` reaches a later
+export.  `merge` folds a worker's export in regardless: the worker only
+recorded because the parent asked it to.
 
 Histograms keep exact count/sum/min/max plus a bounded raw-sample buffer
 (`_SAMPLE_CAP`) from which `summary()` derives mean/p50/p95 —
@@ -33,9 +34,15 @@ class Metrics:
 
     # ----------------------------------------------------------- recording
     def inc(self, name: str, n: float = 1) -> None:
+        """Counter increment; no-op unless the registry is enabled."""
+        if not self.enabled:
+            return
         self.counters[name] = self.counters.get(name, 0) + n
 
     def gauge(self, name: str, value: float) -> None:
+        """Gauge update; no-op unless the registry is enabled."""
+        if not self.enabled:
+            return
         self.gauges[name] = float(value)
 
     def observe(self, name: str, value: float) -> None:
@@ -64,7 +71,7 @@ class Metrics:
 
     def merge(self, exported: Dict[str, Any]) -> None:
         for k, v in (exported.get("counters") or {}).items():
-            self.inc(k, v)
+            self.counters[k] = self.counters.get(k, 0) + v
         self.gauges.update(exported.get("gauges") or {})
         for k, h in (exported.get("histograms") or {}).items():
             mine = self._hists.get(k)

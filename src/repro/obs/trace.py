@@ -21,15 +21,23 @@ JSON that chrome://tracing and https://ui.perfetto.dev load directly.
 
 Everything is allocation-free when disabled: `span` yields immediately
 without creating an event, so tracing can stay threaded through hot code.
+
+While enabled, and once the process has imported `jax` (this module
+never imports it), each span also opens a
+`jax.profiler.TraceAnnotation` of the same name around its block, so a
+`jax.profiler` trace holds every span on its host plane, on the same
+clock as the device operations.  The epoch-microsecond buffer is kept
+as it is either way.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 from typing import Any, Dict, Iterator, List, Optional
 
@@ -66,18 +74,23 @@ class Tracer:
         if not self.enabled:
             yield
             return
-        ts = time.time_ns() // 1000
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            dur = (time.perf_counter_ns() - t0) // 1000
-            self._events.append({
-                "name": name, "cat": "repro", "ph": "X",
-                "ts": int(ts), "dur": int(dur),
-                "pid": os.getpid(), "tid": _tid(),
-                "args": _clean_args(args),
-            })
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        annotation = (profiler.TraceAnnotation(name) if profiler is not None
+                      else nullcontext())
+        with annotation:
+            ts = time.time_ns() // 1000
+            t0 = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                dur = (time.perf_counter_ns() - t0) // 1000
+                self._events.append({
+                    "name": name, "cat": "repro", "ph": "X",
+                    "ts": int(ts), "dur": int(dur),
+                    "pid": os.getpid(), "tid": _tid(),
+                    "args": _clean_args(args),
+                })
 
     def instant(self, name: str, **args: Any) -> None:
         """Record one instant ("i") event (e.g. a pool task failure)."""

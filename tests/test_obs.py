@@ -144,6 +144,103 @@ def test_disabled_obs_records_nothing():
     assert exp["counters"] == {} and exp["histograms"] == {}
 
 
+def test_counters_and_gauges_wait_for_enable():
+    """Counts made while metrics are off (a warm-up pass) never reach a
+    later export; counting starts at `enable`."""
+    obs.counter("scorer.programs", 3)
+    obs.gauge("pool.workers", 2)
+    assert obs.metrics().export()["counters"] == {}
+    assert obs.metrics().export()["gauges"] == {}
+    obs.enable(trace=False, metrics=True, journal=False)
+    obs.counter("scorer.programs")
+    obs.counter("scorer.programs", 2)
+    obs.gauge("pool.workers", 4)
+    exp = obs.metrics().export()
+    assert exp["counters"] == {"scorer.programs": 3}
+    assert exp["gauges"] == {"pool.workers": 4.0}
+
+
+def _within(outers, inner) -> bool:
+    return any(o["ts"] <= inner["ts"] and inner["ts"] + inner["dur"]
+               <= o["ts"] + o["dur"] for o in outers)
+
+
+def test_device_study_spans_nest_at_their_layer():
+    """A `backend="jax"` study records every span and counter of the
+    scorer, the engine rounds and result materialisation, each inside
+    the layer above it, and its result JSON is the one obs-off gives."""
+    kw = dict(apps=["ptb", "wdl"], engine="random", backend="jax",
+              budget=ENGINE_BUDGETS["random"], seed=0)
+    plain = result_bytes(Study(**kw).run())
+    obs.enable(trace=True, metrics=True, journal=False)
+    traced = result_bytes(Study(**kw).run())
+    spans = [e for e in obs.tracer().export() if e.get("ph") == "X"]
+    counters = dict(obs.metrics().counters)
+    assert traced == plain
+
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    parents = {"scorer.code": "evaluate_batch",
+               "scorer.program": "evaluate_batch",
+               "scorer.run": "evaluate_batch",
+               "scorer.area": "evaluate_batch",
+               "evaluate_batch": "evaluator.call",
+               "round.propose": "ask_tell_round",
+               "round.dedup": "ask_tell_round",
+               "evaluator.call": "ask_tell_round",
+               "round.observe": "ask_tell_round",
+               "search.materialize": "search_app",
+               "search.export": "phase.search",
+               "study.rebuild": "phase.search"}
+    for child, parent in parents.items():
+        assert by_name.get(child), f"no {child} span"
+        for e in by_name[child]:
+            assert _within(by_name[parent], e), f"{child} outside {parent}"
+            if child != "evaluate_batch":      # the new spans
+                assert "app" not in e["args"]
+    assert counters["scorer.programs"] == 2      # one bucket per app
+    assert 0 < counters["scorer.rows"] <= counters["scorer.rows_padded"]
+
+
+def test_spans_lie_on_the_profiler_host_plane(tmp_path):
+    """With `jax` imported, an enabled span is also a profiler
+    TraceAnnotation: the trace's host plane holds an event of the same
+    name that starts where the span's epoch timestamp says."""
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+    obs.enable(trace=True, metrics=False, journal=False)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+    except Exception as e:                  # noqa: BLE001
+        pytest.skip(f"the profiler cannot start here: {e}")
+    try:
+        with obs.span("probe.outer", n=4):
+            with obs.span("probe.inner"):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    ours = {e["name"]: e for e in obs.tracer().export()
+            if e.get("ph") == "X"}
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    profile = ProfileData.from_file(str(path))
+    start = None
+    host = {}
+    for plane in profile.planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                start = int(value)
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, ev.start_ns)
+    assert start is not None
+    for name in ("probe.outer", "probe.inner"):
+        assert name in host, f"{name} not on the host plane"
+        epoch_us = (start + host[name]) / 1e3
+        assert abs(epoch_us - ours[name]["ts"]) <= 1000
+
+
 # ------------------------------------------------------------------ journal
 
 @pytest.mark.parametrize("workers", [1, 2])
