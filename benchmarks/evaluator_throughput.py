@@ -18,10 +18,12 @@ promise honest.  It scores the same index-array population four ways:
            (validity screen on joint gather tables, Eq. 1-8 tail only on
            survivors, area folded in) behind the vectorized
            `RowHashCache`.
-  jax    — the live `Evaluator` with `backend="jax"`: one persistent
-           jitted kernel per evaluator with device-resident op tables.
-           Cold (first call, includes compile) and warm steady-state are
-           reported separately; numpy stays the bit-exact reference.
+  jax    — the live `Evaluator` with `backend="jax"`: one jitted kernel
+           per shape, shared by the process, with the evaluator's
+           device-resident op tables as arguments.  Cold (first call:
+           table upload, and the compile unless a program of the same
+           shapes exists) and warm steady-state are reported separately;
+           numpy stays the bit-exact reference.
 
 legacy/array/fused produce bit-identical GOPS vectors (asserted every
 run); jax must agree to 1e-6 relative.  Per-round scoring latency

@@ -18,8 +18,8 @@ matrix feeding an open-addressed int64 table with exact-key collision
 fallback — so probing a 4096-row pool is a handful of array ops, not a
 Python loop.  Cache misses flow through the fused scorer
 (`FusedStreamScorer`, bit-identical to `performance_gops` + `area_many`
-in one pass); `backend="jax"` routes them through the persistent jitted
-kernel in `repro.kernels.costmodel`, and `backend="numpy-ref"` keeps the
+in one pass); `backend="jax"` routes them through the jitted kernel in
+`repro.kernels.costmodel`, and `backend="numpy-ref"` keeps the
 verbatim Eqs. (1)-(13) broadcast reference for parity testing.
 
 `FunctionEvaluator` wraps an arbitrary scalar scoring function (e.g. the
@@ -156,8 +156,8 @@ class Evaluator:
     def _scorer(self):
         """The fused (GOPS, area) scorer for this backend, or None when the
         stream/backend must take the reference `performance_gops` path.
-        Built once and reused — the jax variant holds the persistent jitted
-        function and device-resident op tables."""
+        Built once and reused — the jax variant holds the device-resident
+        op tables; its compiled programs are shared per process."""
         if self._fused_ready:
             return self._fused
         self._fused_ready = True

@@ -355,8 +355,27 @@ def test_cross_round_dedup_counts_repeats(resnet_spec, space):
 
 # ------------------------------------------------------------- jax parity
 
-def test_fused_jax_scorer_parity(resnet_spec, space):
-    jax = pytest.importorskip("jax")
+@pytest.fixture
+def fresh_programs():
+    """An empty process-wide program map, so that counts of programs
+    built do not depend on which tests ran before in this process."""
+    pytest.importorskip("jax")
+    from repro.kernels import costmodel
+    costmodel._PROGRAMS.clear()
+    obs.enable(trace=True, metrics=True, journal=False)
+    try:
+        yield costmodel._PROGRAMS
+    finally:
+        obs.disable(reset=True)
+        costmodel._PROGRAMS.clear()
+
+
+def _program_spans():
+    return [e["args"] for e in obs.tracer().export()
+            if e.get("name") == "scorer.program"]
+
+
+def test_fused_jax_scorer_parity(resnet_spec, space, fresh_programs):
     from repro.kernels.costmodel import FusedJaxScorer
     rng = np.random.default_rng(12)
     batch = space.decode_batch(space.sample_indices(rng, 300))
@@ -366,20 +385,97 @@ def test_fused_jax_scorer_parity(resnet_spec, space):
     want_p, want_a = ref.metrics(batch.matrix)
     jx = FusedJaxScorer(resnet_spec.stream, space.hw, pw, pi,
                         domains=space.domains)
-    obs.enable(trace=False, metrics=True, journal=False)
-    try:
-        got_p, got_a = jx.metrics(batch.matrix)
-        rel = np.abs(got_p - want_p) / np.maximum(np.abs(want_p), 1e-30)
-        assert float(rel.max()) <= 1e-6
-        rel_a = np.abs(got_a - want_a) / np.maximum(np.abs(want_a), 1e-30)
-        assert float(rel_a.max()) <= 1e-6
-        # ragged pool sizes fall into the same padded bucket: no recompile
-        n0 = obs.metrics().counters["scorer.programs"]
-        for n in (300, 301, 299, 260):
-            jx.metrics(batch.matrix[:n])
-        assert obs.metrics().counters["scorer.programs"] == n0 == 1
-    finally:
-        obs.disable(reset=True)
+    got_p, got_a = jx.metrics(batch.matrix)
+    rel = np.abs(got_p - want_p) / np.maximum(np.abs(want_p), 1e-30)
+    assert float(rel.max()) <= 1e-6
+    rel_a = np.abs(got_a - want_a) / np.maximum(np.abs(want_a), 1e-30)
+    assert float(rel_a.max()) <= 1e-6
+    # ragged pool sizes fall into the same padded bucket: no recompile
+    n0 = obs.metrics().counters["scorer.programs"]
+    for n in (300, 301, 299, 260):
+        jx.metrics(batch.matrix[:n])
+    assert obs.metrics().counters["scorer.programs"] == n0 == 1
+
+
+def test_scorers_of_one_shape_share_one_program(resnet_spec, space,
+                                                fresh_programs):
+    """A second scorer on the same stream, domains and bucket (a later
+    `Study`'s) reuses the first one's program: it only uploads."""
+    from repro.kernels.costmodel import FusedJaxScorer
+    rng = np.random.default_rng(13)
+    batch = space.decode_batch(space.sample_indices(rng, 200))
+    args = (resnet_spec.stream, space.hw, resnet_spec.peak_weight_bits,
+            resnet_spec.peak_input_bits)
+    first = FusedJaxScorer(*args, domains=space.domains).metrics(
+        batch.matrix)
+    second = FusedJaxScorer(*args, domains=space.domains).metrics(
+        batch.matrix)
+    counters = obs.metrics().counters
+    assert counters["scorer.programs"] == 1
+    assert counters["scorer.program_reuses"] == 1
+    assert len(fresh_programs) == 1
+    assert [(a["upload"], a["reused"]) for a in _program_spans()] == \
+        [(True, False), (True, True)]
+    for a, b in zip(first, second):
+        assert a.tobytes() == b.tobytes()
+
+
+def test_tables_of_other_shapes_get_their_own_program(space,
+                                                      fresh_programs):
+    from repro.kernels.costmodel import FusedJaxScorer
+    rng = np.random.default_rng(14)
+    batch = space.decode_batch(space.sample_indices(rng, 100))
+    for app in ("resnet", "ptb"):
+        spec = AppSpec.from_graph(app, apps.build_app(app))
+        ref = FusedStreamScorer(spec.stream, space.hw,
+                                spec.peak_weight_bits, spec.peak_input_bits,
+                                domains=space.domains)
+        jx = FusedJaxScorer(spec.stream, space.hw, spec.peak_weight_bits,
+                            spec.peak_input_bits, domains=space.domains)
+        want, got = ref.metrics(batch.matrix)[0], jx.metrics(batch.matrix)[0]
+        rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-30)
+        assert float(rel.max()) <= 1e-6, app
+    counters = obs.metrics().counters
+    assert counters["scorer.programs"] == 2
+    assert counters["scorer.program_reuses"] == 0
+    assert len(fresh_programs) == 2
+
+
+def test_value_set_growth_rebuilds_the_program(space, fresh_programs):
+    """Without `domains` the tables start from the defaults and grow
+    with the pools: each growth re-uploads and, the value-set sizes
+    having changed, builds a new program, within parity."""
+    from repro.kernels.costmodel import FusedJaxScorer
+    spec = AppSpec.from_graph("resnet", apps.build_app("resnet"))
+    kw = dict(peak_weight_bits=spec.peak_weight_bits,
+              peak_input_bits=spec.peak_input_bits)
+    rng = np.random.default_rng(15)
+    batch = space.decode_batch(space.sample_indices(rng, 200))
+    jx = FusedJaxScorer(spec.stream, space.hw, **kw)
+    rebuilds = jx.t.n_rebuilds
+    jx.metrics(batch.matrix[:5])
+    got = jx.metrics(batch.matrix)[0]
+    assert jx.t.n_rebuilds >= rebuilds + 2
+    ref = FusedStreamScorer(spec.stream, space.hw, **kw)
+    want = ref.metrics(batch.matrix)[0]
+    rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-30)
+    assert float(rel.max()) <= 1e-6
+    assert obs.metrics().counters["scorer.programs"] == 2
+    assert [(a["upload"], a["reused"]) for a in _program_spans()] == \
+        [(True, False), (True, False)]
+
+
+def program_shapes(scorer, bucket):
+    """The scorer program's arguments as shapes, and its static `nvals`."""
+    import jax
+    from repro.core.costmodel import _FAST_FIELDS
+    from repro.kernels.costmodel import _COL_FIELDS
+    app, nvals = scorer._app_args()
+    pool = tuple(np.zeros((bucket, len(f)), dtype=np.int64)
+                 for f in (_FAST_FIELDS, _COL_FIELDS))
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype),
+        (*app, *pool)), nvals
 
 
 def test_scorer_program_keeps_the_name_the_benchmark_reads(resnet_spec,
@@ -388,15 +484,11 @@ def test_scorer_program_keeps_the_name_the_benchmark_reads(resnet_spec,
     name `fused_jax_score` (`bench/harness.py` `SCORER_PROGRAM`); a
     rename must fail here, not silently empty its kernel metrics."""
     jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-    from repro.core.costmodel import _FAST_FIELDS
-    from repro.kernels.costmodel import _COL_FIELDS, FusedJaxScorer
+    from repro.kernels.costmodel import FusedJaxScorer, _fused_jit
     jx = FusedJaxScorer(resnet_spec.stream, space.hw,
                         resnet_spec.peak_weight_bits,
                         resnet_spec.peak_input_bits, domains=space.domains)
     with jax.enable_x64(True):
-        jx._ensure_built()
-        args = [jax.ShapeDtypeStruct((256, len(fields)), jnp.int64)
-                for fields in (_FAST_FIELDS, _COL_FIELDS)]
-        text = jx._kern.lower(*args).as_text()
+        args, nvals = program_shapes(jx, 256)
+        text = _fused_jit.lower(*args, nvals=nvals).as_text()
     assert "fused_jax_score" in text

@@ -199,7 +199,11 @@ def test_device_study_spans_nest_at_their_layer():
             assert _within(by_name[parent], e), f"{child} outside {parent}"
             if child != "evaluate_batch":      # the new spans
                 assert "app" not in e["args"]
-    assert counters["scorer.programs"] == 2      # one bucket per app
+    # one bucket per app; the untraced study built both programs, so the
+    # traced one reuses them
+    assert counters["scorer.programs"] + counters["scorer.program_reuses"] \
+        == 2
+    assert counters["scorer.program_reuses"] == 2
     assert 0 < counters["scorer.rows"] <= counters["scorer.rows_padded"]
 
 

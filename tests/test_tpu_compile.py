@@ -9,6 +9,7 @@ never loads the TPU library.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -16,7 +17,8 @@ from repro.core.costmodel import _FAST_FIELDS
 from repro.core.multiapp import AppSpec
 from repro.core.space import default_space
 from repro.kernels import ops
-from repro.kernels.costmodel import _COL_FIELDS, FusedJaxScorer
+from repro.kernels.costmodel import (_COL_FIELDS, FusedJaxScorer,
+                                     _fused_jit)
 
 
 @pytest.fixture(scope="module")
@@ -51,18 +53,21 @@ def no_compile_cache():
 
 @pytest.mark.parametrize("bucket", [256, 4096])
 def test_fused_scorer_compiles_for_v5e(bucket, one_chip, no_compile_cache):
-    """The `backend="jax"` scorer's program (x64, tables baked in) for a
-    traced zoo app at a padded pool bucket."""
+    """The `backend="jax"` scorer's program (x64, op tables as arguments)
+    for a traced zoo app at a padded pool bucket."""
     spec = AppSpec.from_app("qwen2-0.5b:decode")
     space = default_space()
     scorer = FusedJaxScorer(spec.stream, space.hw, spec.peak_weight_bits,
                             spec.peak_input_bits, domains=space.domains)
+    app, nvals = scorer._app_args()
+    pool = tuple(np.zeros((bucket, len(f)), dtype=np.int64)
+                 for f in (_FAST_FIELDS, _COL_FIELDS))
     with jax.enable_x64(True):
-        scorer._ensure_built()
-        args = [jax.ShapeDtypeStruct((bucket, len(fields)), jnp.int64,
-                                     sharding=one_chip)
-                for fields in (_FAST_FIELDS, _COL_FIELDS)]
-        compiled = scorer._kern.lower(*args).compile()
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
+                                           sharding=one_chip),
+            (*app, *pool))
+        compiled = _fused_jit.lower(*args, nvals=nvals).compile()
     assert compiled.memory_analysis().generated_code_size_in_bytes > 0
     header = compiled.as_text().split("\n", 1)[0]
     assert header.startswith("HloModule jit_fused_jax_score")
