@@ -41,7 +41,6 @@ OUT = Path(__file__).resolve().parents[1] / "experiments"
 PAIRS = [
     {
         "arch": "qwen2.5-32b", "shape": "train_4k", "mode": "train",
-        "moe": False,
         "baseline": ExecPoint(sharding_mode="fsdp", remat="full",
                               microbatches=16),
         # hypothesis-driven probes (napkin math in EXPERIMENTS.md §Perf)
@@ -58,16 +57,9 @@ PAIRS = [
     },
     {
         "arch": "olmoe-1b-7b", "shape": "train_4k", "mode": "train",
-        "moe": True,
         "baseline": ExecPoint(sharding_mode="fsdp", remat="full",
                               microbatches=2),
         "probes": {
-            "H1_bigger_moe_groups": ExecPoint(
-                sharding_mode="fsdp", remat="full", microbatches=2,
-                moe_group_size=8192),
-            "H2_smaller_moe_groups": ExecPoint(
-                sharding_mode="fsdp", remat="full", microbatches=2,
-                moe_group_size=2048),
             "H3_tp_params": ExecPoint(
                 sharding_mode="tp", remat="full", microbatches=2),
             "H4_mb1": ExecPoint(
@@ -76,7 +68,6 @@ PAIRS = [
     },
     {
         "arch": "xlstm-1.3b", "shape": "decode_32k", "mode": "decode",
-        "moe": False,
         "baseline": ExecPoint(sharding_mode="tp", remat="none",
                               microbatches=1),
         "probes": {
@@ -124,9 +115,8 @@ def run(max_rounds: int = 4, verbose: bool = True,
             log: list = []
             compiles_before = ev.n_compiles
             best_pt, best_score = autotune_search(
-                ev, engine=engine, shape_mode=pair["mode"],
-                has_moe=pair["moe"], seed=0, max_rounds=max_rounds,
-                init=pair["baseline"], log=log)
+                ev, engine=engine, shape_mode=pair["mode"], seed=0,
+                max_rounds=max_rounds, init=pair["baseline"], log=log)
             entry["search"][engine] = {
                 "best_point": dataclasses.asdict(best_pt),
                 "best_score": best_score,

@@ -4,7 +4,14 @@ vocab=102400, MoE 64e top-6 — MLA kv_lora=512, 2 shared + 64 routed top-6
 
 MLA caches only the 512-d compressed latent + 64-d decoupled RoPE key per
 token (weight-absorbed decode).  Layer 0 is dense (d_ff 10944); layers
-1..26 route over 64 experts (top-6) plus 2 shared experts.
+1..26 route over 64 experts (top-6, softmax scores, no renormalisation)
+plus 2 shared experts.  Routing is dropless (`layers.moe_block`).
+
+As a DSE workload (`repro.frontend.zoo`), the routed experts are costed at
+the load balanced routing gives them: a decode step is 6 matvec instances
+per expert GEMM, only the routed experts' weights streamed; a 128-token
+prefill is 64 instances of 12 rows.  YaRN `rope_scaling` (factor 40,
+mscale 0.707) is not modelled: it is elementwise and uncosted.
 """
 
 from repro.models.config import ArchConfig, MLAConfig, MoEConfig

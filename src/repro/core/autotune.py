@@ -7,7 +7,7 @@ execution design space:
   paper variable        ->  TPU execution variable
   ----------------------------------------------------------------
   PE organisation       ->  sharding_mode (fsdp | tp)
-  loop tiling T*        ->  microbatches, attn_kv_block, moe_group
+  loop tiling T*        ->  microbatches, attn_kv_block
   banked buffers        ->  remat policy (activation residency)
   loop_order            ->  kv cache layout axis (model | data)
 
@@ -52,7 +52,6 @@ class ExecPoint:
     remat: str = "full"                # full | dots | none
     microbatches: int = 1              # gradient accumulation factor
     attn_kv_block: int = 1024          # online-softmax KV tile
-    moe_group_size: int = 4096         # GShard routing group
     extra_rules: Tuple[Tuple[str, Optional[str]], ...] = ()
 
     def key(self) -> str:
@@ -60,8 +59,7 @@ class ExecPoint:
             dataclasses.asdict(self), sort_keys=True).encode()).hexdigest()[:12]
 
     def overrides(self) -> Dict[str, Any]:
-        return {"attn_kv_block": self.attn_kv_block,
-                "moe_group_size": self.moe_group_size}
+        return {"attn_kv_block": self.attn_kv_block}
 
 
 EXEC_DOMAINS: Dict[str, Tuple] = {
@@ -69,7 +67,6 @@ EXEC_DOMAINS: Dict[str, Tuple] = {
     "remat": ("full", "dots", "none"),
     "microbatches": (1, 2, 4, 8, 16),
     "attn_kv_block": (512, 1024, 2048, 4096),
-    "moe_group_size": (2048, 4096, 8192),
     # cache/state layout flips (the paper's loop_order analogue)
     "extra_rules": ((), (("mlstm_state", "model"),),
                     (("kv_seq", None),)),
@@ -134,28 +131,25 @@ class CellEvaluator:
             return list(tp.map(self.score, pts))
 
 
-def _domains_for(shape_mode: str, has_moe: bool) -> Dict[str, Tuple]:
+def _domains_for(shape_mode: str) -> Dict[str, Tuple]:
     d = dict(EXEC_DOMAINS)
     if shape_mode != "train":
         d["microbatches"] = (1,)
         d["remat"] = ("none",)
         d["sharding_mode"] = ("tp",)
-    if not has_moe:
-        d["moe_group_size"] = (4096,)
     return d
 
 
-def exec_space(shape_mode: str = "train", has_moe: bool = False
-               ) -> DiscreteSpace:
+def exec_space(shape_mode: str = "train") -> DiscreteSpace:
     """The TPU execution design space as a generic `DiscreteSpace`, so any
     search engine ("anneal", "genetic", "random", ...) can drive it."""
-    return DiscreteSpace(domains=_domains_for(shape_mode, has_moe),
+    return DiscreteSpace(domains=_domains_for(shape_mode),
                          make_config=lambda **kw: ExecPoint(**kw))
 
 
 def autotune_search(evaluator: CellEvaluator, *, engine: EngineSpec = "greedy",
-                    shape_mode: str = "train", has_moe: bool = False,
-                    seed: int = 0, max_rounds: int = 6,
+                    shape_mode: str = "train", seed: int = 0,
+                    max_rounds: int = 6,
                     init: Optional[ExecPoint] = None,
                     log: Optional[list] = None,
                     **engine_kwargs) -> Tuple[ExecPoint, float]:
@@ -170,14 +164,13 @@ def autotune_search(evaluator: CellEvaluator, *, engine: EngineSpec = "greedy",
     if engine == "greedy":
         # same superset-tolerant kwarg handling make_engine gives the other
         # engines: forward only what greedy_autotune understands
-        return greedy_autotune(evaluator, shape_mode=shape_mode,
-                               has_moe=has_moe, seed=seed,
+        return greedy_autotune(evaluator, shape_mode=shape_mode, seed=seed,
                                max_rounds=max_rounds, init=init, log=log,
                                **filter_kwargs(greedy_autotune,
                                                engine_kwargs))
     from repro.dse import SearchBudget, Study
 
-    space = exec_space(shape_mode, has_moe)
+    space = exec_space(shape_mode)
     # cache misses of each pool flow through score_batch in one call, so a
     # CellEvaluator(compile_workers=N) overlaps its expensive compiles;
     # score-only evaluators (duck-typed) fall back to the scalar path
@@ -221,13 +214,13 @@ def autotune_search(evaluator: CellEvaluator, *, engine: EngineSpec = "greedy",
 
 
 def greedy_autotune(evaluator: CellEvaluator, *, shape_mode: str = "train",
-                    has_moe: bool = False, seed: int = 0,
-                    max_rounds: int = 6, init: Optional[ExecPoint] = None,
+                    seed: int = 0, max_rounds: int = 6,
+                    init: Optional[ExecPoint] = None,
                     delta_threshold: float = 0.02,
                     log: Optional[list] = None) -> Tuple[ExecPoint, float]:
     """Algorithm 1 with k=1 over the execution space (memoized evals)."""
     rng = np.random.default_rng(seed)
-    domains = _domains_for(shape_mode, has_moe)
+    domains = _domains_for(shape_mode)
     s0 = init or ExecPoint()
     p0 = evaluator.score(s0)
     if log is not None:

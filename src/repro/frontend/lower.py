@@ -11,6 +11,12 @@ primitive name to a lowering rule:
                                  (attention heads, MoE experts) become
                                  `repeat` instances via
                                  `Op.batched_matmul`/`Op.batched_matvec`.
+  * ``ragged_dot_general``    -> the dropless grouped expert GEMM
+                                 (`jax.lax.ragged_dot`): M routed rows
+                                 over G experts cost as balanced routing,
+                                 min(G, M) instances of ceil(M / min(G, M))
+                                 rows, so only the experts the routing
+                                 touches are streamed.
   * ``conv_general_dilated``  -> `Op` CONV2D / CHANNEL_MIXING (1x1) /
                                  DEPTHWISE_CONV (feature-group dispatch,
                                  grouped convs as `repeat`ed per-group
@@ -36,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core.costmodel import Op, OpKind
 
 __all__ = ["Lowered", "OperandInfo", "LOWERING_RULES", "register_lowering",
@@ -124,6 +131,46 @@ def _lower_dot_general(eqn, operands, fresh_name, bit_width):
     else:
         op = Op.batched_matmul(col1=k, row1=m, col2=n, instances=inst,
                                name=fresh_name("matmul"))
+    return Lowered(op=op)
+
+
+# ------------------------------------------------------ ragged_dot_general
+
+@register_lowering("ragged_dot_general")
+def _lower_ragged_dot(eqn, operands, fresh_name, bit_width):
+    """Grouped GEMM [M, K] x [G, K, N] (rows sorted by group) -> Table 1
+    rows 4/5 at the load balanced routing gives each group.
+
+    The group sizes are data, unknown to an abstract trace; the rows are
+    spread evenly over min(G, M) groups, each an instance of
+    ceil(M / min(G, M)) rows.  Fewer rows than groups (a decode step's
+    top-k slots) is `Op.batched_matvec`, one instance per routed row.
+    Counts the instances as `frontend.expert_instances`.  Only the
+    forward form (the ragged dimension is a row dimension of the
+    activation) is costed.
+    """
+    rdn = eqn.params["ragged_dot_dimension_numbers"]
+    (lc, rc), (lb, rb) = rdn.dot_dimension_numbers
+    lhs, rhs = operands[0], operands[1]
+    if any(d in lc or d in lb for d in rdn.lhs_ragged_dimensions):
+        return None
+    groups = _prod(rhs.shape[i] for i in rdn.rhs_group_dimensions)
+    k = _prod(lhs.shape[i] for i in lc)
+    rows = _prod(d for i, d in enumerate(lhs.shape)
+                 if i not in lc and i not in lb)
+    n = _prod(d for i, d in enumerate(rhs.shape)
+              if i not in rc and i not in rb
+              and i not in rdn.rhs_group_dimensions)
+    inst = min(groups, rows) * _prod(lhs.shape[i] for i in lb)
+    per_group = -(-rows // min(groups, rows))
+    if per_group == 1:
+        op = Op.batched_matvec(col=k, row=n, instances=inst,
+                               name=fresh_name("expert_matvec"))
+    else:
+        op = Op.batched_matmul(col1=k, row1=per_group, col2=n,
+                               instances=inst,
+                               name=fresh_name("expert_matmul"))
+    obs.counter("frontend.expert_instances", inst)
     return Lowered(op=op)
 
 

@@ -14,15 +14,24 @@ exposes the result under `<arch>:<variant>` names that
 
     variant "prefill" — full-sequence forward at `PREFILL_SEQ` tokens with
                         `last_only` logits (serving prefill); attention is
-                        its two batched matmuls, MoE experts are `repeat`
-                        instances.
+                        its two batched matmuls.
     variant "decode"  — one-token decode step against a `DECODE_CACHE`-
                         slot KV cache; the cache tensors are activation
                         vertices, so the Fig. 5 liveness profile (and the
                         Eq. 13 buffer floor) sees KV-cache residency, and
                         the single-row GEMMs lower to `Op.matvec`.
 
+MoE experts are costed at the load the routing gives them: the dropless
+`moe_block` runs its expert GEMMs as one grouped `ragged_dot` over the
+T x top_k routed rows, which `frontend.lower` costs as balanced routing,
+min(E, T*k) expert instances of ceil(T*k / E) rows.  A decode step is
+top_k matvec instances (only the routed experts' weights stream), a
+128-token prefill over 64 experts at top-6 is 64 instances of 12 rows.
+
 Graphs are memoized per process; listing `ZOO_APP_NAMES` costs nothing.
+Tracing an app records a `frontend.trace` span (args `app`, `ops`,
+`columns`: its unique op columns) and, for MoE models, the counter
+`frontend.expert_instances` while `repro.obs` is enabled.
 """
 
 from __future__ import annotations
@@ -33,6 +42,7 @@ from typing import Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs import ARCH_NAMES, get_arch
 from repro.core.graph import ComputationGraph
 from repro.frontend.trace import trace_to_graph
@@ -138,4 +148,10 @@ def build_zoo_app(name: str) -> ComputationGraph:
     if builder is None:
         raise KeyError(f"unknown variant {variant!r}; "
                        f"available: {sorted(_VARIANT_BUILDERS)}")
-    return builder(arch_name)
+    with obs.span("frontend.trace", app=name) as args:
+        graph = builder(arch_name)
+        if args is not None:
+            stream = graph.op_stream()
+            args.update(ops=len(stream),
+                        columns=len(stream.dedup_columns()[0]))
+    return graph

@@ -24,8 +24,9 @@ padding), `scorer.program` (the first time a scorer meets a bucket: a
 table upload where its `upload` is true, then the program lookup, which
 compiles unless `reused`), `scorer.run` (dispatch, copies, the kernel
 and the blocking readback) and `scorer.area`; counters `scorer.rows`,
-`scorer.rows_padded`, `scorer.programs` (programs compiled) and
-`scorer.program_reuses` (lookups that found one).
+`scorer.rows_padded`, `scorer.cells` (padded rows x unique op columns:
+the [rows, columns] extent of the kernel's gathers), `scorer.programs`
+(programs compiled) and `scorer.program_reuses` (lookups that found one).
 """
 
 from __future__ import annotations
@@ -266,6 +267,7 @@ class FusedJaxScorer:
                 obs.counter("scorer.program_reuses", int(reused))
             obs.counter("scorer.rows", n)
             obs.counter("scorer.rows_padded", m)
+            obs.counter("scorer.cells", m * len(self.t.ops))
             with obs.span("scorer.run", n=n, bucket=m):
                 gops = np.asarray(exe(*self._dev, codes, cols))
         with obs.span("scorer.area", n=n):

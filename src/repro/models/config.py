@@ -25,7 +25,6 @@ class MoEConfig:
     top_k: int
     d_expert: int                  # hidden width of each routed expert
     num_shared: int = 0            # shared (always-on) experts
-    capacity_factor: float = 1.25
     norm_topk_prob: bool = True    # olmoe normalizes; deepseek-v2 does not
     router_dtype: str = "float32"
     first_dense: int = 0           # leading dense layers (deepseek-v2)

@@ -1,6 +1,6 @@
-"""Model-zoo layers: GQA/MLA attention, SwiGLU/GELU MLPs, token-choice MoE,
-RG-LRU recurrent blocks, mLSTM/sLSTM blocks, local (sliding-window)
-attention — all as pure functions over parameter pytrees.
+"""Model-zoo layers: GQA/MLA attention, SwiGLU/GELU MLPs, dropless
+token-choice MoE, RG-LRU recurrent blocks, mLSTM/sLSTM blocks, local
+(sliding-window) attention — all as pure functions over parameter pytrees.
 
 Conventions
 -----------
@@ -52,7 +52,6 @@ class Runtime:
     use_pallas: bool = False
     attn_q_block: int = 512
     attn_kv_block: int = 1024
-    moe_group_size: int = 4096          # tokens routed together (GShard G)
     mlstm_chunk: int = 256
     remat: str = "none"                 # none | full | dots
     kv_dtype: str = "bf16"              # bf16 | f8 (fp8 KV cache, serving)
@@ -603,84 +602,81 @@ def moe_specs(d: int, n_experts: int, d_expert: int,
     return s
 
 
-def moe_block(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
-              capacity_factor: float, normalize_gates: bool,
-              rt: Runtime) -> jax.Array:
-    """Token-choice top-k MoE with capacity dropping (scatter-based).
-
-    Tokens are processed in groups of `rt.moe_group_size` (GShard-style
-    grouping keeps the dispatch buffers sharded along the batch axes).
-    Dispatch/combine are scatter/gather ops — *memory* traffic, not FLOPs —
-    so the roofline compute term reflects only real expert arithmetic.
-    """
-    cd = rt.compute_dtype
-    B, S, D = x.shape
-    T = B * S
-    gsz = min(rt.moe_group_size, T)
-    n_groups = -(-T // gsz)
-    assert T % gsz == 0, (T, gsz)
-    xg = x.reshape(n_groups, gsz, D)
-    xg = rt.shard(xg, "batch", None, None)
-
-    cap = int(math.ceil(gsz * top_k / n_experts * capacity_factor))
-    cap = max(8, -(-cap // 8) * 8)
-
-    logits = jnp.einsum("gtd,de->gte", xg, p["router"].astype(cd),
+def _route_experts(p: Params, xf: jax.Array, lo, *, top_k: int,
+                   normalize_gates: bool, cd) -> jax.Array:
+    """Dropless routed experts of tokens xf [T, D], where the weights in
+    `p` hold experts [lo, lo + E_local) of the router's; slots routed to
+    other experts add nothing.  The slots held here are sorted by expert and the three
+    expert GEMMs run as `jax.lax.ragged_dot` with the group sizes the
+    routing gives, so no expert has a capacity and no buffer is padded."""
+    T, D = xf.shape
+    n_local = p["we1"].shape[0]
+    logits = jnp.einsum("td,de->te", xf, p["router"].astype(cd),
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = jax.lax.top_k(probs, top_k)          # [G, T, k]
+    gate, eidx = jax.lax.top_k(probs, top_k)          # [T, k]
     if normalize_gates:
         gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
-    e_flat = eidx.reshape(n_groups, gsz * top_k)      # [G, T*k]
-    onehot = jax.nn.one_hot(e_flat, n_experts, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=1) * onehot         # [G, T*k, E]
-    pos = pos.sum(-1) - 1                             # position within expert
-    # out-of-capacity updates fall outside [0, cap) and are dropped
-    slot = jnp.where(pos < cap, e_flat * cap + pos, n_experts * cap)
+    e_flat = eidx.reshape(-1) - lo                    # [T*k] local expert
+    held = (e_flat >= 0) & (e_flat < n_local)
+    e_flat = jnp.where(held, e_flat, n_local)         # others sort last
+    order = jnp.argsort(e_flat, stable=True)          # grouped by expert
+    sizes = jnp.bincount(e_flat, length=n_local + 1)[:n_local]
+    # a token's top-k experts are distinct, so at most min(k, E_local) of
+    # its slots are held here: the sorted rows past that bound are unheld
+    rows = T * min(top_k, n_local)
+    xs = xf[order[:rows] // top_k]                    # [rows, D] dispatch
 
-    # Dispatch via an *index* scatter (tiny: int32 [G, E*C]) followed by a
-    # token gather — GSPMD partitions gathers cleanly along the group dim,
-    # whereas scattering activation vectors into [G, E, C, D] replicates
-    # the whole buffer on every shard.
-    src_tok = jnp.broadcast_to(
-        jnp.arange(gsz, dtype=jnp.int32)[None, :, None],
-        (n_groups, gsz, top_k)).reshape(n_groups, gsz * top_k)
-    gidx = jnp.arange(n_groups)[:, None]
-    slot_to_src = jnp.full((n_groups, n_experts * cap + 1), gsz, jnp.int32)
-    slot_to_src = slot_to_src.at[gidx, slot].set(src_tok, mode="drop")
-    slot_to_src = slot_to_src[:, :-1]                 # [G, E*C]
-    slot_to_src = rt.shard(slot_to_src, "batch")
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w.astype(cd), sizes.astype(jnp.int32),
+                                  preferred_element_type=jnp.float32)
 
-    x_pad = jnp.concatenate([xg, jnp.zeros((n_groups, 1, D), xg.dtype)], 1)
-    buf = jnp.take_along_axis(x_pad, slot_to_src[..., None],
-                              axis=1)                 # [G, E*C, D]
-    buf = buf.reshape(n_groups, n_experts, cap, D)
-    buf = rt.shard(buf, "batch", "experts")
+    h = (jax.nn.silu(grouped(xs, p["we1"]))
+         * grouped(xs, p["we3"])).astype(cd)
+    ys = grouped(h, p["we2"]).astype(cd)              # [rows, D]
 
-    we1 = p["we1"].astype(cd)
-    we3 = p["we3"].astype(cd)
-    we2 = p["we2"].astype(cd)
-    g1 = jnp.einsum("gecd,edf->gecf", buf, we1,
-                    preferred_element_type=jnp.float32)
-    u1 = jnp.einsum("gecd,edf->gecf", buf, we3,
-                    preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g1) * u1).astype(cd)
-    h = rt.shard(h, "batch", "experts")
-    y_e = jnp.einsum("gecf,efd->gecd", h, we2,
-                     preferred_element_type=jnp.float32).astype(cd)
-    y_e = rt.shard(y_e, "batch", "experts")
+    # combine: undo the sort, weight each held slot by its gate
+    y_rep = ys[jnp.minimum(jnp.argsort(order), rows - 1)]
+    y_rep = jnp.where(held[:, None], y_rep, jnp.zeros((), cd))
+    y_rep = y_rep.reshape(T, top_k, D)
+    return (y_rep * gate[..., None].astype(cd)).sum(axis=1)
 
-    # combine: gather each (token, k)'s expert output back
-    y_flat = y_e.reshape(n_groups, n_experts * cap, D)
-    safe_slot = jnp.minimum(slot, n_experts * cap - 1)
-    y_rep = jnp.take_along_axis(y_flat, safe_slot[..., None],
-                                axis=1)               # [G, T*k, D]
-    dropped = (slot >= n_experts * cap)[..., None]
-    y_rep = jnp.where(dropped, jnp.zeros((), cd), y_rep)
-    y = (y_rep.reshape(n_groups, gsz, top_k, D)
-         * gate[..., None].astype(cd)).sum(axis=2)
-    y = y.reshape(B, S, D)
+
+def moe_block(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
+              normalize_gates: bool, rt: Runtime) -> jax.Array:
+    """Token-choice top-k MoE, dropless (every routed token is computed).
+
+    On a mesh the block is a `shard_map`: each device routes the tokens of
+    its batch shard to the experts of its "experts" shard, and a psum over
+    the experts axis combines them, so dispatch stays local to the batch
+    shard and the expert weights stay sharded.  Dispatch and combine are
+    a gather and its inverse permutation: *memory* traffic, not FLOPs.
+    """
+    cd = rt.compute_dtype
+    B, S, D = x.shape
+    assert p["router"].shape[1] == n_experts, (p["router"].shape, n_experts)
+    routed = {k: p[k] for k in ("router", "we1", "we3", "we2")}
+    route = functools.partial(_route_experts, top_k=top_k,
+                              normalize_gates=normalize_gates, cd=cd)
+    if rt.mesh is None or rt.rules is None:
+        y = route(routed, x.reshape(B * S, D), 0).reshape(B, S, D)
+    else:
+        P = jax.sharding.PartitionSpec
+        batch = rt.rules.get("batch")
+        ex = rt.rules.get("experts")
+
+        def local(q, xl):
+            lo = (jax.lax.axis_index(ex) * q["we1"].shape[0]) if ex else 0
+            yl = route(q, xl.reshape(-1, D), lo).reshape(xl.shape)
+            return jax.lax.psum(yl, ex) if ex else yl
+
+        w_spec = P(ex, None, None)
+        y = jax.shard_map(
+            local, mesh=rt.mesh,
+            in_specs=({"router": P(), "we1": w_spec, "we3": w_spec,
+                       "we2": w_spec}, P(batch)),
+            out_specs=P(batch))(routed, x)
 
     if "shared" in p:
         y = y + swiglu(p["shared"], x, rt)

@@ -142,7 +142,6 @@ def block_apply_train(cfg: ArchConfig, kind: str, p: Params, x: jax.Array,
             m = cfg.moe
             y = L.moe_block(p["moe"], h2, n_experts=m.num_experts,
                             top_k=m.top_k,
-                            capacity_factor=m.capacity_factor,
                             normalize_gates=m.norm_topk_prob, rt=rt)
         else:
             y = L.swiglu(p["mlp"], h2, rt)
@@ -237,7 +236,6 @@ def block_apply_decode(cfg: ArchConfig, kind: str, p: Params, x: jax.Array,
             m = cfg.moe
             y = L.moe_block(p["moe"], h2, n_experts=m.num_experts,
                             top_k=m.top_k,
-                            capacity_factor=m.capacity_factor,
                             normalize_gates=m.norm_topk_prob, rt=rt)
         else:
             y = L.swiglu(p["mlp"], h2, rt)

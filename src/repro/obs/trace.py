@@ -68,11 +68,14 @@ class Tracer:
 
     # ----------------------------------------------------------- recording
     @contextmanager
-    def span(self, name: str, **args: Any) -> Iterator[None]:
+    def span(self, name: str,
+             **args: Any) -> Iterator[Optional[Dict[str, Any]]]:
         """Record one complete ("X") event covering the with-block.  A
-        no-op (no allocation, no clock read) while disabled."""
+        no-op (no allocation, no clock read) while disabled.  Yields the
+        span's `args` (None while disabled), so that the block can add
+        attributes it learns only as it runs."""
         if not self.enabled:
-            yield
+            yield None
             return
         jax = sys.modules.get("jax")
         profiler = getattr(jax, "profiler", None)
@@ -82,7 +85,7 @@ class Tracer:
             ts = time.time_ns() // 1000
             t0 = time.perf_counter_ns()
             try:
-                yield
+                yield args
             finally:
                 dur = (time.perf_counter_ns() - t0) // 1000
                 self._events.append({

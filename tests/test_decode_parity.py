@@ -21,15 +21,7 @@ PARITY_ARCHS = [n for n in configs.ARCH_NAMES
 
 @pytest.mark.parametrize("name", PARITY_ARCHS)
 def test_forward_vs_decode_logits(name):
-    import dataclasses
     cfg = configs.get_smoke(name)
-    if cfg.moe is not None:
-        # parity requires drop-free routing: the train path routes per
-        # 4096-token group while decode routes per step, so capacity
-        # dropping (a *training* throughput trade-off) breaks teacher
-        # forcing equivalence by design.  Compare drop-free.
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
     model = build_model(cfg)
     params = model.init(KEY, RT)
     B, S = 2, 12

@@ -66,3 +66,43 @@ def test_small_mesh_dryrun_end_to_end():
     assert res["peak"] > 0
     res2 = json.loads(lines[1].split(" ", 1)[1])
     assert res2["flops"] > 0
+
+
+MOE_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import json
+    import jax
+    from repro import configs
+    from repro.configs.shapes import ShapeSpec
+    from repro.core.roofline import measure_compiled
+    from repro.launch.mesh import make_mesh
+    from repro.launch.steps import build_step_bundle
+
+    mesh = make_mesh((2, 4), ("data", "model"))
+    arch = configs.get_smoke("olmoe-1b-7b")
+    shape = ShapeSpec("tiny_train", seq_len=64, global_batch=8, mode="train")
+    bundle = build_step_bundle(arch, shape, mesh, microbatches=2)
+    with mesh:
+        compiled = bundle.lower().compile()
+        flops, hbm, coll, peak = measure_compiled(compiled)
+    print("RESULT " + json.dumps({"flops": flops, "coll": coll.total_bytes,
+                                  "peak": peak}))
+""")
+
+
+@pytest.mark.slow
+def test_small_mesh_dryrun_moe_train_step():
+    """The MoE train step (its expert `shard_map` inside the layer scan,
+    remat and gradient accumulation) compiles on a (data, model) mesh."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", MOE_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT")]
+    res = json.loads(line[-1].split(" ", 1)[1])
+    assert res["flops"] > 0
+    assert res["coll"] > 0            # experts sharded on "model" psum
+    assert res["peak"] > 0

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 from jax import lax
 
+from repro import configs
 from repro.core import apps
 from repro.core.apps import _B
 from repro.core.costmodel import OpKind
@@ -13,6 +14,8 @@ from repro.core.multiapp import AppSpec
 from repro.core.search import optimize_for_app
 from repro.core.space import default_space
 from repro.frontend import trace_to_graph
+from repro.frontend.zoo import DECODE_CACHE, PREFILL_SEQ
+from repro.models.layers import Runtime
 
 
 def _op_sig(op):
@@ -122,6 +125,100 @@ def test_dot_batch_dims_become_repeat_instances():
     assert (op.nif, op.nix, op.nof) == (32, 16, 16)
     # activation x activation: no parameters attached
     assert _stream_nodes(g)[0].weight_bits == 0
+
+
+@pytest.mark.parametrize("rows, kind, instances, per_group", [
+    (6, OpKind.MATVEC, 6, 1),        # fewer rows than experts (decode)
+    (64, OpKind.MATVEC, 64, 1),      # one row per expert
+    (768, OpKind.MATMUL, 64, 12),    # prefill: 128 tokens x top-6
+    (100, OpKind.MATMUL, 64, 2),     # uneven: rows round up per expert
+])
+def test_ragged_dot_costs_balanced_routing(rows, kind, instances,
+                                           per_group):
+    """Grouped expert GEMM [M, D] x [G, D, F] (`lax.ragged_dot`): min(G, M)
+    instances of ceil(M / min(G, M)) rows; the whole expert bank's
+    parameters attach to it once."""
+    G, D, F = 64, 32, 48
+    w = jax.ShapeDtypeStruct((G, D, F), jnp.float32)
+    x = jax.ShapeDtypeStruct((rows, D), jnp.float32)
+    sizes = jax.ShapeDtypeStruct((G,), jnp.int32)
+
+    def fn(p, x, sizes):
+        return lax.ragged_dot(x, p, sizes)
+
+    g = trace_to_graph(fn, w, x, sizes, name="moe")
+    (node,) = _stream_nodes(g)
+    op = node.op
+    assert (op.kind, op.repeat, op.nif) == (kind, instances, D)
+    if kind == OpKind.MATVEC:
+        assert (op.nix, op.nof) == (F, 1)
+    else:
+        assert (op.nix, op.nof) == (per_group, F)
+    assert op.macs == instances * per_group * D * F
+    assert node.weight_bits == G * D * F * 8
+
+
+def _deepseek_plain_macs(variant: str, kv_block: int = 0) -> int:
+    """deepseek-v2-lite-16b's MACs from its layer equations.  Decode: one
+    token against DECODE_CACHE latent slots (absorbed MLA).  Prefill:
+    PREFILL_SEQ tokens (expanded MLA), keys padded to `kv_block` when it
+    is given (`blocked_attention` pads them to its KV block), logits of
+    the last token only."""
+    a = configs.get_arch("deepseek-v2-lite-16b")
+    m, e = a.mla, a.moe
+    d, h = a.d_model, a.num_heads
+    nope, rope_d, lora, vd = (m.qk_nope_head_dim, m.qk_rope_head_dim,
+                              m.kv_lora_rank, m.v_head_dim)
+    t = 1 if variant == "decode" else PREFILL_SEQ
+    proj = t * (d * h * (nope + rope_d)          # W_q
+                + d * (lora + rope_d)            # W_dkv and W_kr
+                + h * vd * d)                    # W_o
+    if variant == "decode":
+        ctx = DECODE_CACHE
+        attn = proj + h * (nope * lora           # q_nope absorbs W_uk
+                           + ctx * (lora + rope_d)   # scores
+                           + ctx * lora          # probs x latent
+                           + lora * vd)          # W_uv
+    else:
+        keys = -(-t // kv_block) * kv_block if kv_block else t
+        attn = (proj + t * lora * h * (nope + vd)    # W_ukv
+                + h * t * keys * (nope + rope_d + vd))
+    moe = t * (d * e.num_experts                 # router
+               + e.top_k * 3 * d * e.d_expert    # routed experts
+               + 3 * d * e.d_expert * e.num_shared)
+    dense = t * 3 * d * e.dense_d_ff
+    head = d * a.vocab_size
+    return (e.first_dense * (attn + dense)
+            + (a.num_layers - e.first_dense) * (attn + moe) + head)
+
+
+def test_deepseek_decode_costs_the_plain_count():
+    """2,511,470,592 MACs; each MoE layer's routed GEMMs are 6 matvec
+    instances (the top-6), no op spans all 64 experts."""
+    stream = apps.build_app("deepseek-v2-lite-16b:decode").op_stream()
+    assert stream.total_macs == _deepseek_plain_macs("decode") \
+        == 2_511_470_592
+    a = configs.get_arch("deepseek-v2-lite-16b")
+    experts = [op for op in stream.ops if op.repeat == a.moe.top_k]
+    assert len(experts) == 3 * (a.num_layers - a.moe.first_dense)
+    assert all(op.kind == OpKind.MATVEC for op in experts)
+    assert not [op for op in stream.ops if op.repeat == a.moe.num_experts]
+
+
+def test_deepseek_prefill_costs_the_plain_count():
+    """305,253,056,512 MACs: the plain 289,398,587,392 plus
+    15,854,469,120 of keys padded from 128 to the 1024-key block;
+    expert GEMMs are 64 instances of 12 rows (768 routed rows)."""
+    stream = apps.build_app("deepseek-v2-lite-16b:prefill").op_stream()
+    block = Runtime().attn_kv_block
+    assert stream.total_macs == _deepseek_plain_macs("prefill", block) \
+        == 305_253_056_512
+    assert _deepseek_plain_macs("prefill") == 289_398_587_392
+    a = configs.get_arch("deepseek-v2-lite-16b")
+    experts = [op for op in stream.ops if op.repeat == a.moe.num_experts
+               and op.nix == 12]
+    assert len(experts) == 3 * (a.num_layers - a.moe.first_dense)
+    assert all(op.kind == OpKind.MATMUL for op in experts)
 
 
 def test_scan_pjit_remat_are_traversed():

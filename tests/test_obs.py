@@ -207,6 +207,49 @@ def test_device_study_spans_nest_at_their_layer():
     assert 0 < counters["scorer.rows"] <= counters["scorer.rows_padded"]
 
 
+def test_frontend_trace_and_scorer_width_are_counted():
+    """Tracing a zoo app records one `frontend.trace` span (its op count
+    and unique op columns) and counts the instances of its grouped expert
+    GEMMs (26 MoE layers x 3 GEMMs x top-6 routed rows in decode, x 64
+    experts in prefill); a scorer call counts its padded rows x unique op
+    columns.  Disabled, nothing is recorded, and the graphs are the same."""
+    from repro.frontend.zoo import build_zoo_app
+    from repro.kernels.costmodel import FusedJaxScorer
+    apps = {"deepseek-v2-lite-16b:decode": 468,
+            "deepseek-v2-lite-16b:prefill": 4992}
+    build_zoo_app.cache_clear()
+    plain = {a: build_zoo_app(a).op_stream().field_matrix for a in apps}
+    assert len(obs.tracer()) == 0
+    assert obs.metrics().export()["counters"] == {}
+
+    build_zoo_app.cache_clear()
+    obs.enable(trace=True, metrics=True, journal=False)
+    streams = {}
+    for app, instances in apps.items():
+        before = obs.metrics().counters.get("frontend.expert_instances", 0)
+        streams[app] = build_zoo_app(app).op_stream()
+        assert obs.metrics().counters["frontend.expert_instances"] \
+            - before == instances
+        np.testing.assert_array_equal(streams[app].field_matrix,
+                                      plain[app])
+    spans = [e for e in obs.tracer().export()
+             if e.get("name") == "frontend.trace"]
+    assert [e["args"]["app"] for e in spans] == list(apps)
+    for e in spans:
+        stream = streams[e["args"]["app"]]
+        assert e["args"]["ops"] == len(stream)
+        assert e["args"]["columns"] == len(stream.dedup_columns()[0])
+
+    space = default_space()
+    batch = space.decode_batch(space.sample_indices(
+        np.random.default_rng(0), 40))
+    app = "deepseek-v2-lite-16b:decode"
+    FusedJaxScorer(streams[app], space.hw,
+                   domains=space.domains).metrics(batch.matrix)
+    columns = len(streams[app].dedup_columns()[0])
+    assert obs.metrics().counters["scorer.cells"] == 256 * columns
+
+
 def test_spans_lie_on_the_profiler_host_plane(tmp_path):
     """With `jax` imported, an enabled span is also a profiler
     TraceAnnotation: the trace's host plane holds an event of the same

@@ -261,7 +261,7 @@ def test_generic_engines_drive_exec_space():
     for engine in ("anneal", "genetic", "random"):
         cell = FakeCell()
         best, score = autotune_search(cell, engine=engine, shape_mode="train",
-                                      has_moe=True, seed=0, max_rounds=5)
+                                      seed=0, max_rounds=5)
         assert score > 0
         assert best.microbatches in (1, 2, 4, 8, 16)
         # memoization: strictly fewer scorer calls than proposals
