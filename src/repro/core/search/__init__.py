@@ -32,7 +32,8 @@ The driver is deliberately dumb::
 
 `run_search(engine, evaluator)` implements exactly this loop and returns a
 `SearchResult` (best / history / every evaluated config + score — the
-top-10 % candidate selection of §5.1 consumes the full log).
+top-10 % candidate selection of §5.1 consumes the full log; on the
+accelerator space that log is one `ConfigBatch`).
 
 The shared Evaluator
 ====================

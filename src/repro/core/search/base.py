@@ -312,7 +312,9 @@ class SearchResult:
     best: Any
     best_perf: float
     history: List[Tuple[Any, float]]       # per-round incumbent
-    evaluated: List[Any]                   # every scored config, in order
+    # every scored config, in order: a `ConfigBatch` for accelerator
+    # configs (`evaluated[i]` materializes one), else a list
+    evaluated: Sequence[Any]
     evaluated_perf: np.ndarray             # aligned scores (scalarized)
     rounds: int
     engine: str = ""
@@ -332,7 +334,8 @@ class SearchResult:
         incumbent is the earliest result holding the maximum `best_perf`
         (strict ``>`` — exactly the historical multi-restart rule) and
         contributes its `history`/`engine`.  `rounds` sum.  `evaluator`
-        defaults to the first result's handle."""
+        defaults to the first result's handle.  Batch logs concatenate to
+        one batch (`_join_logs`); mixed or generic logs to a list."""
         results = list(results)
         if not results:
             raise ValueError("cannot merge zero SearchResults")
@@ -340,12 +343,15 @@ class SearchResult:
         for r in results[1:]:
             if r.best_perf > best.best_perf:
                 best = r
-        evaluated: List[Any] = []
+        evaluated = _join_logs([r.evaluated for r in results])
+        if isinstance(evaluated, list):
+            obs.counter("search.rows_materialized",
+                        sum(len(r.evaluated) for r in results
+                            if not isinstance(r.evaluated, list)))
         perf: List[float] = []
         values: List[np.ndarray] = []
         rounds = 0
         for r in results:
-            evaluated.extend(r.evaluated)
             perf.extend(np.asarray(r.evaluated_perf,
                                    dtype=np.float64).tolist())
             if r.evaluated_values is not None:
@@ -561,13 +567,34 @@ class _CrossRoundDedup:
         return skipped
 
 
+def _join_logs(parts: Sequence[Sequence[Any]]) -> Sequence[Any]:
+    """One evaluated log from its parts (round pools or restart logs), in
+    the given order.
+
+    Where every non-empty part holds accelerator configs (a `ConfigBatch`,
+    or a list of `AccelConfig`) the log is one `ConfigBatch`, and no row
+    becomes a dataclass.  Otherwise (generic spaces, such as autotune's
+    `ExecPoint`) it is a flat list of the parts' items."""
+    from repro.core.costmodel import AccelConfig, ConfigBatch
+    parts = [p for p in parts if len(p)]
+    if parts and all(isinstance(p, ConfigBatch)
+                     or all(isinstance(c, AccelConfig) for c in p)
+                     for p in parts):
+        if len(parts) == 1 and isinstance(parts[0], ConfigBatch):
+            return parts[0]
+        return ConfigBatch.concat([ConfigBatch.from_configs(p)
+                                   for p in parts])
+    return [c for p in parts for c in p]
+
+
 def run_search(engine: Optimizer, evaluator) -> SearchResult:
     """Drive `engine` to completion through `evaluator`; collect the log.
 
     Engines may propose either config-object lists or array-native
-    `ConfigBatch` pools; batches stay arrays through scoring and are only
-    materialized to dataclasses once, after the loop, for the
-    `SearchResult.evaluated` log.
+    `ConfigBatch` pools.  Accelerator pools stay arrays through scoring and
+    into the `SearchResult.evaluated` log, which is then one `ConfigBatch`
+    (`_join_logs`); counter `search.rows_materialized` counts the rows of
+    a log that is a list instead.
 
     When the evaluator returns an [N, M] objective-value matrix (vector
     objective), the driver scalarizes ONCE through the engine's hook —
@@ -613,11 +640,10 @@ def run_search(engine: Optimizer, evaluator) -> SearchResult:
                         time.perf_counter() - t0)
         if jrn is not None:
             jrn.emit(pool, scalar, dedup_skipped=round_skipped)
-    evaluated: List[Any] = []
     with obs.span("search.materialize", n=sum(len(p) for p in pools)):
-        for pool in pools:
-            evaluated.extend(pool.to_configs()
-                             if hasattr(pool, "to_configs") else pool)
+        evaluated = _join_logs(pools)
+    obs.counter("search.rows_materialized",
+                len(evaluated) if isinstance(evaluated, list) else 0)
     best = engine.best
     best_perf = float(engine.best_perf)
     if best is None and evaluated:          # engine kept no incumbent

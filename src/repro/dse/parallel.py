@@ -19,7 +19,8 @@ out over a process pool while keeping every result **deterministic**:
     shard (stream + hw + peaks + budget + backend + injected
     objective/constraints).  Each worker builds its shard locally, scores
     through it, and ships the shard's raw-metric cache back for a
-    deterministic `Evaluator.cache_merge` on the parent.
+    deterministic `Evaluator.cache_merge` on the parent (a task run in
+    the parent's own process hands its live evaluator over instead).
   * `_search_app_task` / `_score_shard_task` / `_cross_eval_task` — the
     module-level worker functions (picklable under the ``spawn`` start
     method) for per-app searches, sharded population scoring, and sharded
@@ -285,12 +286,16 @@ class EvalParams:
 def _search_app_task(payload: Dict[str, Any]) -> Dict[str, Any]:
     """Run one application's multi-restart search in a worker.
 
-    Returns a portable record (no live evaluator handle): the incumbent,
-    the full evaluated log as a `ConfigBatch`, the worker shard's
-    raw-metric cache for the parent-side merge, and — when the payload
-    carries obs wire state and this is a fresh pool process — the task's
-    exported trace/journal/metrics buffers (`"obs"`, None on the
-    in-process path, where events land in the live parent buffers)."""
+    Returns a record of the incumbent, the full evaluated log as a
+    `ConfigBatch`, and — when the payload carries obs wire state and this
+    is a fresh pool process — the task's exported trace/journal/metrics
+    buffers (`"obs"`, None on the in-process path, where events land in
+    the live parent buffers).  Run in the process that built the payload
+    (`"origin_pid"`: the serial executor, `workers=1`, serial
+    degradation), the record hands over the live evaluator; in a pool
+    process it carries the evaluator's raw-metric cache instead, for the
+    parent-side merge (a live evaluator holds device buffers and is never
+    pickled)."""
     owned = obs.begin_task(payload.get("obs"))
     prev_ctx = obs.get_context()
     obs.set_context(app=payload["name"])
@@ -315,15 +320,18 @@ def _search_app_task(payload: Dict[str, Any]) -> Dict[str, Any]:
                 "best_perf": float(res.best_perf),
                 "history": list(res.history),
                 "evaluated": (ConfigBatch.from_configs(res.evaluated)
-                              if res.evaluated else None),
+                              if len(res.evaluated) else None),
                 "evaluated_perf": np.asarray(res.evaluated_perf,
                                              dtype=np.float64),
                 "evaluated_values": res.evaluated_values,
                 "rounds": int(res.rounds),
                 "engine": res.engine,
-                "cache": ev.cache_export(),
                 "stats": ev.stats(),
             }
+            if payload.get("origin_pid") == os.getpid():
+                record["evaluator"] = ev
+            else:
+                record["cache"] = ev.cache_export()
     finally:
         export = obs.end_task(owned)
         if not owned:

@@ -145,7 +145,8 @@ def _combine_chunk_records(recs: Sequence[Dict]) -> Dict:
     Mirrors `SearchResult.merge` exactly: earliest strict-max incumbent
     (which also contributes history/engine), logs concatenated in chunk
     order, rounds summed.  Shard caches are content-addressed, so the
-    first writer wins without conflicts; stats counters sum."""
+    first writer wins without conflicts (a chunk that ran in-process
+    exports its live evaluator's); stats counters sum."""
     best = recs[0]
     for r in recs[1:]:
         if float(r["best_perf"]) > float(best["best_perf"]):
@@ -155,7 +156,9 @@ def _combine_chunk_records(recs: Sequence[Dict]) -> Dict:
               if r.get("evaluated_values") is not None]
     cache: Dict = {}
     for r in recs:
-        for k, v in (r.get("cache") or {}).items():
+        shard = (r["evaluator"].cache_export() if "evaluator" in r
+                 else r.get("cache") or {})
+        for k, v in shard.items():
             cache.setdefault(k, v)
     stats: Dict[str, int] = {}
     for r in recs:
@@ -174,6 +177,7 @@ def _combine_chunk_records(recs: Sequence[Dict]) -> Dict:
         "rounds": sum(int(r["rounds"]) for r in recs),
         "engine": best["engine"],
         "cache": cache,
+        "folded": len(recs),
         "stats": stats,
         "obs": None,              # chunk exports merge separately
     }
@@ -670,23 +674,33 @@ class Study:
                 "engine_kwargs": dict(self.budget.engine_kwargs) or None,
                 "seed": self.seed + 7919 * j + 1000 * int(offset),
                 "params": self._eval_params(spec, share),
-                "obs": obs.wire_state()}
+                "obs": obs.wire_state(),
+                "origin_pid": os.getpid()}
 
     def _rebuild_result(self, j: int, rec: Dict) -> SearchResult:
-        """Portable worker record -> SearchResult with a parent-side
-        evaluator warmed from the worker shard's raw-metric cache (the
-        synthesis stages re-read raw metrics; merged keys are content-
-        addressed, so values are identical to an in-process run)."""
+        """Worker record -> SearchResult, keeping the `ConfigBatch` log.
+
+        An in-process record hands over its live evaluator.  A pool
+        record's evaluator is built here and warmed from the worker
+        shard's raw-metric cache (counter `study.cache_merges`, one per
+        record folded): the synthesis stages re-read raw metrics, and
+        merged keys are content-addressed, so values are identical to
+        the in-process run's."""
         batch = rec.get("evaluated")
         with obs.span("study.rebuild",
                       n=len(batch) if batch is not None else 0):
-            ev = self._job_evaluator(j)
-            if rec.get("cache"):
-                ev.cache_merge(rec["cache"])
-            evaluated = batch.to_configs() if batch is not None else []
+            ev = rec.get("evaluator")
+            folded = 0
+            if ev is None:
+                ev = self._job_evaluator(j)
+                if rec.get("cache"):
+                    ev.cache_merge(rec["cache"])
+                folded = rec.get("folded", 1)
+            obs.counter("study.cache_merges", folded)
         return SearchResult(
             best=rec["best"], best_perf=float(rec["best_perf"]),
-            history=list(rec.get("history", [])), evaluated=evaluated,
+            history=list(rec.get("history", [])),
+            evaluated=batch if batch is not None else [],
             evaluated_perf=np.asarray(rec["evaluated_perf"],
                                       dtype=np.float64),
             rounds=int(rec["rounds"]), engine=rec.get("engine", ""),
@@ -811,11 +825,6 @@ class Study:
         for c in self.constraints:
             constraint_from_describe(c.describe())     # raises if custom
 
-    def _codec(self):
-        if getattr(self, "_codec_cache", None) is None:
-            self._codec_cache = self._search_space.codec()
-        return self._codec_cache
-
     def _spec_record(self) -> Dict:
         """The full declarative problem (everything `from_spec` needs)."""
         rec = {
@@ -882,15 +891,16 @@ class Study:
         as codec index rows (exact integer round-trip); floats survive via
         repr round-trip, so a decoded result reproduces the original
         synthesis inputs bit-for-bit."""
-        codec = self._codec()
+        log = res.evaluated
+        idx = (self._search_space.encode_batch(
+            ConfigBatch.from_configs(log)).tolist() if len(log) else [])
         return {
             "name": self._job_label(i),
             "best": _cfg_dict(res.best),
             "best_perf": float(res.best_perf),
             "engine": res.engine,
             "rounds": int(res.rounds),
-            "evaluated": (codec.encode(res.evaluated).tolist()
-                          if res.evaluated else []),
+            "evaluated": idx,
             "evaluated_perf": np.asarray(res.evaluated_perf,
                                          dtype=np.float64).tolist(),
             "evaluated_values": (res.evaluated_values.tolist()
@@ -900,9 +910,9 @@ class Study:
         }
 
     def _decode_result(self, i: int, rec: Dict) -> SearchResult:
-        codec = self._codec()
+        space = self._search_space
         idx = np.asarray(rec.get("evaluated", []), dtype=np.int64)
-        evaluated = (codec.decode(idx.reshape(-1, codec.n_vars))
+        evaluated = (space.decode_batch(idx.reshape(-1, len(space.variables)))
                      if idx.size else [])
         values = rec.get("evaluated_values")
         return SearchResult(
@@ -975,14 +985,16 @@ class Study:
         else:
             idx = np.asarray([int(np.argmax(perf))])
         order = idx[np.argsort(-perf[idx])]
+        log = res.evaluated
+        # dedupe on the log's rows; only the picks become dataclasses
+        rows = ConfigBatch.from_configs(log).matrix
         seen = set()
         cands: List[Any] = []
-        for j in order:
-            cfg = res.evaluated[int(j)]
-            key = tuple(sorted(cfg.asdict().items()))
+        for j in order.tolist():
+            key = rows[j].tobytes()
             if key not in seen:
                 seen.add(key)
-                cands.append(cfg)
+                cands.append(log[j])
             if len(cands) >= self.max_candidates_per_app:
                 break
         return cands

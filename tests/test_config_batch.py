@@ -308,6 +308,103 @@ def test_engines_propose_array_native_pools(space, resnet_spec):
     assert all(isinstance(c, AccelConfig) for c in res.evaluated)
 
 
+def _exec_point_score(pt) -> float:
+    return float(pt.microbatches) / (1 + abs(pt.attn_kv_block - 2048))
+
+
+@pytest.mark.parametrize("engine,kw", [
+    ("random", dict(max_rounds=2, batch=6)),
+    ("greedy", dict(max_rounds=3, k=1)),           # round 0 is a list
+    ("anneal", dict(max_rounds=3, chains=4)),
+    ("genetic", dict(max_rounds=2, population=8)),
+    ("exec_space", dict(max_rounds=2, batch=4)),   # generic ExecPoints
+])
+def test_run_search_log_type(engine, kw, space, resnet_spec):
+    """Over the accelerator space the evaluated log is one `ConfigBatch`
+    whose rows are the per-config log of the rounds' pools, and whose
+    items are still `AccelConfig`; a generic space keeps a list.  Counter
+    `search.rows_materialized` reads 0 for a batch log and the rows
+    logged for a list."""
+    from repro import obs
+    from repro.core.search import make_engine
+    if engine == "exec_space":
+        from repro.core.autotune import exec_space
+        sp, ev = exec_space("train"), FunctionEvaluator(_exec_point_score)
+        eng = make_engine("random", sp, ev, seed=0, **kw)
+    else:
+        ev = Evaluator.for_space(
+            resnet_spec.stream, space,
+            peak_weight_bits=resnet_spec.peak_weight_bits,
+            peak_input_bits=resnet_spec.peak_input_bits)
+        eng = make_engine(engine, space, ev, seed=0, **kw)
+    pools = []
+    propose = eng.propose
+
+    def recording_propose():
+        pool = propose()
+        pools.append(pool)
+        return pool
+
+    eng.propose = recording_propose
+    obs.disable(reset=True)
+    obs.enable(trace=False, metrics=True, journal=False)
+    try:
+        res = run_search(eng, ev)
+        rows_materialized = obs.metrics().counters["search.rows_materialized"]
+    finally:
+        obs.disable(reset=True)
+    old_log = [b[i] for b in pools for i in range(len(b))]
+    assert len(res.evaluated) == len(old_log) > 0
+    if engine == "exec_space":
+        assert isinstance(res.evaluated, list)
+        assert res.evaluated == old_log
+        assert rows_materialized == len(old_log)
+        return
+    assert isinstance(res.evaluated, ConfigBatch)
+    np.testing.assert_array_equal(res.evaluated.matrix,
+                                  ConfigBatch.from_configs(old_log).matrix)
+    assert all(isinstance(c, AccelConfig) for c in res.evaluated)
+    assert rows_materialized == 0
+
+
+@pytest.mark.parametrize("kinds,want", [
+    (("batch", "batch", "batch"), ConfigBatch),
+    (("batch", "empty", "batch"), ConfigBatch),
+    (("list", "batch"), ConfigBatch),              # AccelConfig lists join
+    (("batch", "generic"), list),
+    (("generic", "generic"), list),
+])
+def test_search_result_merge_log_type(kinds, want, space):
+    """`SearchResult.merge` joins batch logs into one batch in the given
+    order and falls back to a list on mixed or generic logs; either way
+    the merged log reads back the parts' configs in order."""
+    from repro.core.search import SearchResult
+    rng = np.random.default_rng(3)
+    logs, flat = [], []
+    for kind in kinds:
+        cfgs = [space.sample(rng) for _ in range(3)]
+        if kind == "batch":
+            logs.append(ConfigBatch.from_configs(cfgs))
+        elif kind == "list":
+            logs.append(cfgs)
+        elif kind == "generic":
+            cfgs = [{"tile": int(c.tif)} for c in cfgs]
+            logs.append(cfgs)
+        else:
+            cfgs = []
+            logs.append([])
+        flat.extend(cfgs)
+    results = [SearchResult(best=None, best_perf=float(i), history=[],
+                            evaluated=log,
+                            evaluated_perf=np.arange(len(log), dtype=float),
+                            rounds=1)
+               for i, log in enumerate(logs)]
+    merged = SearchResult.merge(results)
+    assert isinstance(merged.evaluated, want)
+    assert list(merged.evaluated) == flat
+    assert len(merged.evaluated_perf) == len(flat)
+
+
 def test_function_evaluator_batch_score_fn():
     calls = {"scalar": 0, "batch": 0}
 

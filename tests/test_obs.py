@@ -168,7 +168,9 @@ def _within(outers, inner) -> bool:
 def test_device_study_spans_nest_at_their_layer():
     """A `backend="jax"` study records every span and counter of the
     scorer, the engine rounds and result materialisation, each inside
-    the layer above it, and its result JSON is the one obs-off gives."""
+    the layer above it, and its result JSON is the one obs-off gives.
+    Materialisation's counters read 0: no row of the log becomes a
+    dataclass and no row cache is exported and merged."""
     kw = dict(apps=["ptb", "wdl"], engine="random", backend="jax",
               budget=ENGINE_BUDGETS["random"], seed=0)
     plain = result_bytes(Study(**kw).run())
@@ -205,6 +207,9 @@ def test_device_study_spans_nest_at_their_layer():
         == 2
     assert counters["scorer.program_reuses"] == 2
     assert 0 < counters["scorer.rows"] <= counters["scorer.rows_padded"]
+    # the evaluated log stays a batch and the live evaluator is handed over
+    assert counters["search.rows_materialized"] == 0
+    assert counters["study.cache_merges"] == 0
 
 
 def test_frontend_trace_and_scorer_width_are_counted():

@@ -279,6 +279,87 @@ def test_worker_count_invariance_pareto():
     assert outs[1] == outs[2] == outs[4]
 
 
+@pytest.mark.parametrize("backend,workers", [("numpy", 1), ("jax", 1),
+                                             ("numpy", 2)])
+def test_search_log_handoff(backend, workers):
+    """The evaluated log reaches synthesis as a `ConfigBatch`.  In-process
+    (`workers=1`) the study hands over the live evaluator: no row becomes
+    a dataclass and no cache is exported and merged.  A pool run merges
+    one cache per record the pool returned.  Either way the result is the
+    one a serial, obs-off run gives, and the evaluator's cache holds
+    every logged row (the read-back `bench/check.py` relies on)."""
+    from repro import obs
+    from repro.core.costmodel import ConfigBatch
+    kw = dict(apps=["ptb", "wdl"], engine="random",
+              budget=ENGINE_BUDGETS["random"], seed=0, backend=backend)
+    plain = result_bytes(Study(workers=1, **kw).run())
+    obs.disable(reset=True)
+    obs.enable(trace=False, metrics=True, journal=False)
+    try:
+        result = Study(workers=workers, **kw).run()
+        counters = dict(obs.metrics().counters)
+    finally:
+        obs.disable(reset=True)
+    assert result_bytes(result) == plain
+    assert counters["search.rows_materialized"] == 0
+    # restarts=1: the pool returns one record per app
+    assert counters["study.cache_merges"] == (0 if workers == 1 else 2)
+    for res in result.per_app_results.values():
+        assert isinstance(res.evaluated, ConfigBatch)
+        assert set(res.evaluated.row_keys()) <= \
+            set(res.evaluator.cache_export())
+
+
+def test_degraded_restart_chunks_fold_their_live_caches(tmp_path):
+    """Restart chunks that fell back to in-process execution hand over
+    live evaluators; combining them exports and folds each one's cache,
+    so the rebuilt evaluator still holds every logged row and the result
+    is the serial one."""
+    from repro import obs
+    kw = dict(apps=["ptb"], engine="random", seed=0,
+              budget=SearchBudget(restarts=2, max_rounds=3,
+                                  engine_kwargs={"batch": 12}))
+    baseline = result_bytes(Study(**kw).run())
+    ex = ParallelExecutor(workers=2, max_retries=0,
+                          fault=FaultPlan(state_dir=str(tmp_path / "f"),
+                                          mode="raise", times=999))
+    obs.disable(reset=True)
+    obs.enable(trace=False, metrics=True, journal=False)
+    try:
+        with pytest.warns(ParallelExecutionWarning, match="serial"):
+            result = Study(executor=ex, **kw).run()
+        merges = obs.metrics().counters["study.cache_merges"]
+    finally:
+        obs.disable(reset=True)
+    assert ex.degraded
+    assert result_bytes(result) == baseline
+    assert merges == 2                       # two restart chunks folded
+    res = result.per_app_results["ptb"]
+    assert set(res.evaluated.row_keys()) <= set(res.evaluator.cache_export())
+
+
+def test_resume_restores_a_batch_log(tmp_path):
+    """A study resumed from its checkpoint holds the same log type as a
+    fresh one, for the app decoded from the checkpoint and for the app
+    searched after it, and gives the byte-identical result."""
+    from repro.core.costmodel import ConfigBatch
+    kw = dict(SMALL, engine="random", budget=ENGINE_BUDGETS["random"])
+    baseline = result_bytes(Study(**kw).run())
+    ckpt = tmp_path / "batch.ckpt"
+
+    def boom(n):
+        if n == 1:
+            raise Crash
+
+    with pytest.raises(Crash):
+        Study(**kw).run(checkpoint_path=ckpt, checkpoint_every=1,
+                        on_checkpoint=boom)
+    resumed = Study.resume(ckpt)
+    assert result_bytes(resumed) == baseline
+    for res in resumed.per_app_results.values():
+        assert isinstance(res.evaluated, ConfigBatch)
+
+
 def test_parallel_reproduces_greedy_goldens():
     """The seed-commit greedy golden survives the process pool bit-for-bit
     (worker-side evaluator shards change nothing)."""
