@@ -1,7 +1,7 @@
 """Shared result I/O for the BENCH_*.json files.
 
-The three committed benchmark baselines (BENCH_evaluator.json,
-BENCH_study.json, BENCH_surrogate.json) used to be written by three
+The committed benchmark baselines (BENCH_study.json,
+BENCH_surrogate.json, BENCH_composition.json) used to be written by
 hand-rolled `json.dumps` calls with nothing but the raw numbers; a
 regression investigated weeks later had no record of which host, commit,
 or date produced the baseline.  Every writer now goes through
@@ -10,7 +10,7 @@ envelope::
 
     {
       "bench_schema": 2,
-      "bench": "evaluator_throughput",
+      "bench": "study_scaling",
       "host": {"platform": ..., "python": ..., "cpu_count": ...},
       "git_rev": "f1c3693",            # null outside a git checkout
       "timestamp": "2026-08-08T12:34:56Z",

@@ -348,7 +348,7 @@ class AccelConfig:
 
 
 # Canonical field order for every array view of the design space.  Cache
-# keys, ConfigBatch matrices, and the broadcast kernels all follow it.
+# keys, ConfigBatch matrices, and the scorers all follow it.
 _CFG_FIELDS = ("loop_order", "pe_group", "mac_per_group", "bank_height",
                "bank_width", "weight_banks_pg", "act_banks_pg",
                "tif", "tix", "tiy", "tof",
@@ -543,50 +543,18 @@ def evaluate_stream_many(
     hw: HardwareConstants = HardwareConstants(),
     peak_weight_bits: int = 0,
     peak_input_bits: int = 0,
-    backend: str = "numpy",
-    with_parts: bool = True,
-) -> Tuple[np.ndarray, np.ndarray, Optional[Dict[str, np.ndarray]]]:
-    """Evaluate many configurations against one op stream.
+) -> Tuple[np.ndarray, np.ndarray, Dict[str, np.ndarray]]:
+    """Evaluate many configurations against one op stream: the reference.
 
-    `configs` may be a sequence of `AccelConfig` or an array-native
-    `ConfigBatch` (the fast path — no per-config attribute loops).
-
-    Backends (all bit-for-bit / within-rounding equivalent):
-      "numpy"     (default) table-driven gather kernel for large pools —
-                  every `[C, O]` term that depends on the config through
-                  one or two small-domain fields is computed once per
-                  unique field value and gathered, killing the per-element
-                  int64 divisions; falls back to the reference below for
-                  small pools or degenerate streams.  Bit-identical to the
-                  reference (integer table lookups are exact).
-      "numpy-ref" the verbatim Eqs. (1)-(13) broadcast formulas below —
-                  the reference every other backend is tested against.
-      "jax"       the same formulas jit-compiled (float64/int64 via x64
-                  mode); same results within float rounding, faster on
-                  accelerator-backed hosts.
+    The verbatim Eqs. (1)-(13) broadcast over `[C, O]`, which every scorer
+    (`FusedStreamScorer` on numpy, `FusedJaxScorer` on the device) is
+    tested against.  `configs` may be a sequence of `AccelConfig` or a
+    `ConfigBatch`.
 
     Returns ``(total_cycles[C], valid[C], parts)`` where parts carries the
-    [C, O] compute / weight / input cycle matrices for analysis
-    (``with_parts=False`` lets the fast path skip materializing them —
-    cycles/valid only, as the scoring hot loop consumes).
+    [C, O] compute / weight / input / total cycle matrices and the per-op
+    validity for analysis.
     """
-    if backend == "jax":
-        return _evaluate_stream_many_jax(configs, stream, hw,
-                                         peak_weight_bits, peak_input_bits,
-                                         with_parts=with_parts)
-    if backend == "numpy":
-        n_cfg = (len(configs) if not isinstance(configs, ConfigBatch)
-                 else configs.matrix.shape[0])
-        if (n_cfg >= _FAST_PATH_MIN_POOL and len(stream)
-                and bool((stream.nkx > 0).all() and (stream.nky > 0).all()
-                         and (stream.s > 0).all())):
-            return _evaluate_stream_many_fast(configs, stream, hw,
-                                              peak_weight_bits,
-                                              peak_input_bits,
-                                              with_parts=with_parts)
-    elif backend != "numpy-ref":
-        raise ValueError(f"unknown backend {backend!r}; "
-                         "expected 'numpy', 'numpy-ref' or 'jax'")
     c = _configs_to_arrays(configs)
     o = stream  # row vectors [1, O]
 
@@ -703,272 +671,20 @@ def evaluate_stream_many(
 
 
 # --------------------------------------------------------------------------
-# Default numpy fast path: table-driven gather kernel.
-#
-# Every [C, O] term above that is expensive (the int64 ceil-divisions)
-# depends on the configuration only through ONE or TWO fields, and design-
-# space fields take a handful of distinct values (power-of-two domains).  So
-# each such term is computed once per unique field value (or value pair) as
-# a tiny [U, O] table and *gathered* to [C, O] — a memcpy instead of C*O
-# integer divisions.  All table entries are integers computed by the exact
-# reference expressions, so the gathered results are bit-identical to the
-# reference kernel; the float tail (Eqs. 7-8 division/ceil, the loop-order
-# selects, the final max/sum) is shared verbatim.
-# --------------------------------------------------------------------------
-
-_FAST_PATH_MIN_POOL = 64     # below this the table setup outweighs the wins
-# row-chunk size for the formula tail: keeps the ~20 live [chunk, U]
-# temporaries cache-resident instead of streaming the full pool through
-# DRAM ~60 times (bit-exact: rows are independent, per-row op order and the
-# axis-1 reductions are unchanged)
-_FAST_PATH_CHUNK = 512
-
-_FAST_FIELDS = ("tif", "tix", "tiy", "tof", "pif", "pof", "pox", "poy",
-                "pkx", "pky", "pb")
-
-
-def _evaluate_stream_many_fast(
-    configs: "Sequence[AccelConfig] | ConfigBatch",
-    stream: OpStream,
-    hw: HardwareConstants,
-    peak_weight_bits: int = 0,
-    peak_input_bits: int = 0,
-    with_parts: bool = True,
-) -> Tuple[np.ndarray, np.ndarray, Optional[Dict[str, np.ndarray]]]:
-    c = _configs_to_arrays(configs)
-    # cost the unique op columns only; repeated layers are restored by the
-    # `expand` gather before the (order-preserving, hence bit-exact) axis-1
-    # reductions below
-    o, expand = stream.dedup_columns()
-
-    uvals: Dict[str, np.ndarray] = {}
-    inv: Dict[str, np.ndarray] = {}
-    for f in _FAST_FIELDS:
-        uvals[f], inv[f] = np.unique(c[f][:, 0], return_inverse=True)
-
-    def pair_idx(fa: str, fb: str):
-        """Unique (fa, fb) value pairs + per-config row index into them."""
-        nb = len(uvals[fb])
-        ucode, pinv = np.unique(inv[fa] * nb + inv[fb], return_inverse=True)
-        return uvals[fa][ucode // nb], uvals[fb][ucode % nb], pinv
-
-    def triple_idx(fa: str, fb: str, fc: str):
-        nb, nc = len(uvals[fb]), len(uvals[fc])
-        code = (inv[fa] * nb + inv[fb]) * nc + inv[fc]
-        ucode, tinv = np.unique(code, return_inverse=True)
-        ia, rem = ucode // (nb * nc), ucode % (nb * nc)
-        return (uvals[fa][ia], uvals[fb][rem // nc], uvals[fc][rem % nc],
-                tinv)
-
-    def col(v: np.ndarray) -> np.ndarray:
-        return v[:, None]
-
-    # ---- tables (same expressions as the reference, computed once per
-    # unique field value / value pair).  Tables sharing an index array are
-    # stacked so each costs ONE gather in the chunk loop below; products of
-    # factors that live on the same table are folded at table level
-    # (integer multiplication is exact, so the fold is bit-preserving). ----
-    def tox_of(tix_vals: np.ndarray) -> np.ndarray:
-        return np.clip((np.minimum(col(tix_vals), o.nix) - o.nkx) // o.s + 1,
-                       1, o.nox)
-
-    def toy_of(tiy_vals: np.ndarray) -> np.ndarray:
-        return np.clip((np.minimum(col(tiy_vals), o.niy) - o.nky) // o.s + 1,
-                       1, o.noy)
-
-    # {pb}: batch iterations + effective batch unroll
-    p_b_t = np.minimum(col(uvals["pb"]), o.batch)
-    pb_tbl = np.stack([_ceil_div(o.batch, p_b_t), p_b_t])
-
-    # {tif, pif}: inner-tiling factor + effective input-channel unroll
-    tif_u, pif_u, i_ifp = pair_idx("tif", "pif")
-    tmp = np.minimum(col(tif_u), o.nif)
-    p_if_t = np.minimum(col(pif_u), tmp)
-    ifp_tbl = np.stack([_ceil_div(tmp, p_if_t), p_if_t])
-
-    # {tof, pof}
-    tof_u, pof_u, i_ofp = pair_idx("tof", "pof")
-    tmp = np.minimum(col(tof_u), o.nof)
-    p_of_t = np.minimum(col(pof_u), tmp)
-    ofp_tbl = np.stack([_ceil_div(tmp, p_of_t), p_of_t])
-
-    # {tix, pox}
-    tix_u, pox_u, i_xp = pair_idx("tix", "pox")
-    tmp = tox_of(tix_u)
-    p_ox_t = np.minimum(col(pox_u), tmp)
-    xp_tbl = np.stack([_ceil_div(tmp, p_ox_t), p_ox_t])
-
-    # {tiy, poy}
-    tiy_u, poy_u, i_yp = pair_idx("tiy", "poy")
-    tmp = toy_of(tiy_u)
-    p_oy_t = np.minimum(col(poy_u), tmp)
-    yp_tbl = np.stack([_ceil_div(tmp, p_oy_t), p_oy_t])
-
-    # {pkx, pky}: kernel-window inner factors and unrolls, pre-folded
-    pkx_u, pky_u, i_kk = pair_idx("pkx", "pky")
-    p_kx_t = np.minimum(col(pkx_u), o.nkx)
-    p_ky_t = np.minimum(col(pky_u), o.nky)
-    kk_tbl = np.stack([_ceil_div(o.nkx, p_kx_t) * _ceil_div(o.nky, p_ky_t),
-                       p_kx_t * p_ky_t])
-
-    # {tix, pox, pkx} / {tiy, poy, pky}: the Eq. (2) input windows
-    tix_w, pox_w, pkx_w, i_wx = triple_idx("tix", "pox", "pkx")
-    in_win_x_t = ((np.minimum(col(pox_w), tox_of(tix_w)) - 1) * o.s
-                  + np.minimum(col(pkx_w), o.nkx))
-    tiy_w, poy_w, pky_w, i_wy = triple_idx("tiy", "poy", "pky")
-    in_win_y_t = ((np.minimum(col(poy_w), toy_of(tiy_w)) - 1) * o.s
-                  + np.minimum(col(pky_w), o.nky))
-
-    # {tif, tof}: Eq. (3) channel-tile product + Eq. (10) weight tile
-    tif_w, tof_w, i_wt = pair_idx("tif", "tof")
-    t_if_w = np.minimum(col(tif_w), o.nif)
-    t_of_w = np.minimum(col(tof_w), o.nof)
-    wt_tbl = np.stack([
-        _ceil_div(o.nif, t_if_w) * _ceil_div(o.nof, t_of_w),
-        o.nkx * o.nky * t_if_w * t_of_w,                     # Eq. (10) tile
-        _ceil_div(o.nof, t_of_w),                            # ofm tiles
-    ])
-
-    # {tix, tiy}: Eq. (3) spatial-tile product (= loop-order refetch count)
-    tix_s, tiy_s, i_sp = pair_idx("tix", "tiy")
-    spatial_t = (_ceil_div(o.nox, tox_of(tix_s))
-                 * _ceil_div(o.noy, toy_of(tiy_s)))
-
-    # ---- triple tables for the Eq. (12) activation tile ----
-    tix3, tiy3, tif3, i_a1 = triple_idx("tix", "tiy", "tif")
-    atile_in_t = (np.minimum(col(tix3), o.nix)
-                  * np.minimum(col(tiy3), o.niy)
-                  * np.minimum(col(tif3), o.nif))
-    tix4, tiy4, tof4, i_a2 = triple_idx("tix", "tiy", "tof")
-    atile_out_t = (tox_of(tix4) * toy_of(tiy4)
-                   * np.minimum(col(tof4), o.nof))
-
-    # ---- op-only rows [1, O], hoisted out of the chunk loop ----
-    num_weight = (o.nox * o.noy * o.nkx * o.nky * o.nif * o.nof
-                  * o.repeat).astype(np.float64)             # Eq. (5)
-    num_input = num_weight * o.batch                         # Eq. (6)
-    ws_weight = (o.weight_elems_arr() * 1.0)
-    ie_batch = o.input_elems_arr() * o.batch
-    is_input = (o.input_elems_arr() * o.batch * 1.0)
-    max_batch = o.batch.max()
-
-    # ---- gather + formula tail per row chunk (identical formulas to the
-    # reference kernel above; chunking only changes cache residency) ----
-    n_cfg = next(iter(c.values())).shape[0]
-    n_ops = len(stream)
-    out_cycles = np.empty(n_cfg, dtype=np.float64)
-    out_valid = np.empty(n_cfg, dtype=bool)
-    parts = None
-    if with_parts:
-        parts = {
-            "compute": np.empty((n_cfg, n_ops), dtype=np.int64),
-            "weight": np.empty((n_cfg, n_ops), dtype=np.float64),
-            "input": np.empty((n_cfg, n_ops), dtype=np.float64),
-            "total": np.empty((n_cfg, n_ops), dtype=np.float64),
-            "valid_ops": np.empty((n_cfg, n_ops), dtype=bool),
-        }
-    for start in range(0, n_cfg, _FAST_PATH_CHUNK):
-        ch = slice(start, start + _FAST_PATH_CHUNK)
-        g = pb_tbl[:, inv["pb"][ch]]
-        batch_iters, pb = g[0], g[1]
-        g = ifp_tbl[:, i_ifp[ch]]
-        cd_if, pif = g[0], g[1]
-        g = ofp_tbl[:, i_ofp[ch]]
-        cd_of, pof = g[0], g[1]
-        g = xp_tbl[:, i_xp[ch]]
-        cd_ox, pox = g[0], g[1]
-        g = yp_tbl[:, i_yp[ch]]
-        cd_oy, poy = g[0], g[1]
-        g = kk_tbl[:, i_kk[ch]]
-        cd_kk, p_kxky = g[0], g[1]
-        g = wt_tbl[:, i_wt[ch]]
-        chan_tiles, wtile, ofm_tiles = g[0], g[1], g[2]
-        spatial_tiles = spatial_t[i_sp[ch]]
-        in_win_x = in_win_x_t[i_wx[ch]]
-        in_win_y = in_win_y_t[i_wy[ch]]
-        need_w_tile = wtile * hw.bit_width                   # Eq. (10)
-        need_a_tile = (atile_in_t[i_a1[ch]]
-                       + atile_out_t[i_a2[ch]]) * hw.bit_width
-
-        poxy = pox * poy
-        unroll = pif * pof * poxy * p_kxky * pb
-        total_macs = c["pe_group"][ch] * c["mac_per_group"][ch]
-        valid_macs = unroll <= total_macs                    # Eq. (9)
-
-        # the ceil(Nk/Tk) factors are exactly 1 (Tkx=Nkx, Tky=Nky; guarded
-        # >0 by the dispatcher) and are dropped from the Eq. (3) products
-        inter = chan_tiles * spatial_tiles
-        inner = cd_if * cd_kk * cd_ox * cd_oy * cd_of
-        compute_cycles = inter * inner * batch_iters * o.repeat
-
-        weight_reuse = poxy * pb                             # Eq. (1)
-        input_reuse = np.maximum(
-            (pof * p_kxky * poxy)
-            // np.maximum(in_win_x * in_win_y, 1), 1)        # Eq. (2)
-
-        lo = c["loop_order"][ch]
-        ws_input = (ie_batch * ofm_tiles).astype(np.float64)
-        os_weight = (o.weight_elems_arr()
-                     * spatial_tiles).astype(np.float64)
-        os_input = ws_input
-        is_weight = os_weight
-
-        num_weight_eff = np.where(
-            lo == LoopOrder.PAPER, num_weight / np.maximum(weight_reuse, 1),
-            np.where(lo == LoopOrder.WEIGHT_STATIONARY, ws_weight,
-                     np.where(lo == LoopOrder.OUTPUT_STATIONARY, os_weight,
-                              is_weight)))
-        num_input_eff = np.where(
-            lo == LoopOrder.PAPER, num_input / np.maximum(input_reuse, 1),
-            np.where(lo == LoopOrder.WEIGHT_STATIONARY, ws_input,
-                     np.where(lo == LoopOrder.OUTPUT_STATIONARY, os_input,
-                              is_input)))
-
-        wbw = np.maximum(c["weight_banks_pg"][ch] * c["pe_group"][ch]
-                         * c["bank_width"][ch] // hw.bit_width, 1)
-        abw = np.maximum(c["act_banks_pg"][ch] * c["pe_group"][ch]
-                         * c["bank_width"][ch] // hw.bit_width, 1)
-        weight_cycles = np.ceil(num_weight_eff / wbw)        # Eq. (7)
-        input_cycles = np.ceil(num_input_eff / abw)          # Eq. (8)
-
-        total = np.maximum(compute_cycles,
-                           np.maximum(weight_cycles, input_cycles))
-
-        wbuf = (c["weight_banks_pg"][ch] * c["pe_group"][ch]
-                * c["bank_height"][ch] * c["bank_width"][ch])
-        abuf = (c["act_banks_pg"][ch] * c["pe_group"][ch]
-                * c["bank_height"][ch] * c["bank_width"][ch])
-        valid_buf = (wbuf >= need_w_tile) & (abuf >= need_a_tile)
-        if peak_weight_bits:
-            valid_buf = valid_buf & (wbuf >= peak_weight_bits)  # Eq. (11)
-        if peak_input_bits:
-            valid_buf = valid_buf & (abuf >= peak_input_bits * max_batch)
-
-        valid_ops = valid_macs & valid_buf
-        if parts is not None:
-            parts["compute"][ch] = compute_cycles[:, expand]
-            parts["weight"][ch] = weight_cycles[:, expand]
-            parts["input"][ch] = input_cycles[:, expand]
-            parts["total"][ch] = total[:, expand]
-            parts["valid_ops"][ch] = valid_ops[:, expand]
-        # all() over repeated columns equals all() over the unique ones
-        out_valid[ch] = valid_ops.all(axis=1)
-        # the sum must run over the original column layout (float addition
-        # order matters for bit-exactness with the reference)
-        out_cycles[ch] = total[:, expand].sum(axis=1)
-    return out_cycles, out_valid, parts
-
-
-# --------------------------------------------------------------------------
 # Fused scoring hot path: persistent tables + validity-first screening.
 #
 # `FusedStreamScorer` is the evaluation pipeline's steady-state kernel.  It
-# differs from `_evaluate_stream_many_fast` in three ways, all bit-exact:
+# differs from the `evaluate_stream_many` reference in four ways, all
+# bit-exact:
 #
-#   1. **Persistent tables.**  The [U, O] gather tables are built once per
-#      (stream, hw, field-value set) — domain-complete when the caller hands
-#      over the `DesignSpace` domains, grown lazily from observed pool
-#      values otherwise — instead of re-`np.unique`-ing every pool.  Row
+#   1. **Gather tables.**  Every [C, O] term that is expensive (the int64
+#      ceil-divisions) depends on the configuration only through one to
+#      four fields, and design-space fields take a handful of values.  So
+#      each term is computed once per field value (or value tuple) on the
+#      unique op columns as a small [U, O] table, and gathered per row.
+#      The tables are built once per (stream, hw, field-value set) —
+#      domain-complete when the caller hands over the `DesignSpace`
+#      domains, grown lazily from observed pool values otherwise.  Row
 #      codes come from O(1) value->index lookup arrays.
 #   2. **Validity first.**  The Eq. (9)-(13) constraint screen needs only
 #      cheap table gathers and integer compares; configurations that fail
@@ -979,15 +695,21 @@ def _evaluate_stream_many_fast(
 #      become row partitions: each row's branch is computed once instead of
 #      computing all four branches for every row.  Per-row values are
 #      unchanged (same expressions, same dtypes, same order).
+#   4. **Column dedup.**  Repeated op columns are costed once and restored
+#      by the `expand` gather before the axis-1 sum.
 #
 # Bit-exactness notes: all pre-division quantities are int64; int64
 # multiplication is exact mod 2^64 and therefore associative/commutative,
 # so folding factor products into joint tables cannot change any value
 # (including the wraparound cases the reference would also wrap).  Floats
 # enter exactly where the reference converts (Eqs. 7-8 and the final max /
-# expand-sum), in the same order.  Area is the verbatim `area_many`
-# expression, fused into the same pass.
+# expand-sum), in the same order; the sum runs over the original column
+# layout.  Area is the verbatim `area_many` expression, fused into the
+# same pass.
 # --------------------------------------------------------------------------
+
+_FAST_FIELDS = ("tif", "tix", "tiy", "tof", "pif", "pof", "pox", "poy",
+                "pkx", "pky", "pb")
 
 # value->code lookup arrays are dense over [0, max_value]; fields with
 # absurdly large values (hand-built configs, not space-sampled ones) fall
@@ -1055,7 +777,7 @@ class _FusedTables:
                 out.append(np.tile(np.repeat(v[f], reps_in), reps_out))
             return out
 
-        # -- base pair/triple tables (verbatim fast-path expressions) --
+        # -- base pair/triple tables (the reference's expressions) --
         p_b = np.minimum(col(v["pb"]), o.batch)
         self.pb_tbl = np.stack([_ceil_div(o.batch, p_b), p_b])
 
@@ -1219,10 +941,11 @@ _FUSED_CHUNK = 1024
 class FusedStreamScorer:
     """Fused (GOPS, area) scorer for `ConfigBatch` matrices on one stream.
 
-    `metrics(matrix)` returns exactly what
-    `(performance_gops(batch, ...), area_many(batch, ...))` returns —
-    bit-for-bit, asserted by `tests/test_fused_eval.py` across the zoo —
-    in one pass: constraint screen, latency tail on survivors, area.
+    `metrics(matrix)` returns exactly what the reference
+    `(performance_gops(batch, ..., backend="numpy-ref"), area_many(batch,
+    ...))` returns — bit-for-bit, asserted by `tests/test_fused_eval.py`
+    across the zoo — in one pass: constraint screen, latency tail on
+    survivors, area.
 
     Use `FusedStreamScorer.supports(stream)` before constructing; streams
     with zero-size kernels or strides (where `tox_of` would divide by
@@ -1310,8 +1033,8 @@ class FusedStreamScorer:
     def _cycles(self, matrix: np.ndarray, code: Dict[str, np.ndarray],
                 rows: np.ndarray) -> np.ndarray:
         """Eq. (1)-(8) latency tail on the constraint-surviving rows —
-        the verbatim fast-path formulas, loop-order branches computed per
-        row partition instead of via nested `np.where`."""
+        the reference's formulas on gathered tables, loop-order branches
+        computed per row partition instead of via nested `np.where`."""
         t, hw = self.t, self.hw
         J = ConfigBatch._INDEX
         nv = t.nvals
@@ -1400,157 +1123,6 @@ class FusedStreamScorer:
         return out
 
 
-# --------------------------------------------------------------------------
-# Optional jax backend: the same Eqs. (1)-(13) broadcast kernel, jit-compiled.
-# numpy above remains the default and the reference; this exists because the
-# population x op-stream [C, O] scoring shape is exactly what accelerators
-# eat.  Kernels are cached per (bit_width); shapes recompile on change.
-# --------------------------------------------------------------------------
-
-_JAX_KERNEL_CACHE: Dict[int, object] = {}
-
-
-def _jax_broadcast_kernel(bit_width: int):
-    kern = _JAX_KERNEL_CACHE.get(bit_width)
-    if kern is not None:
-        return kern
-    import jax
-    import jax.numpy as jnp
-
-    def _cdiv(a, b):
-        return -(-a // jnp.maximum(b, 1))
-
-    def kernel(cfgm, streamm, peak_weight_bits, peak_input_scaled):
-        c = {f: cfgm[:, j:j + 1] for j, f in enumerate(_CFG_FIELDS)}
-        s = {f: streamm[j:j + 1, :] for j, f in enumerate(OpStream.FIELDS)}
-        weight_elems = (s["nif"] * s["nkx"] * s["nky"] * s["nof"]
-                        * s["repeat"])
-        input_elems = s["nif"] * s["nix"] * s["niy"] * s["repeat"]
-
-        tif = jnp.minimum(c["tif"], s["nif"])
-        tix = jnp.minimum(c["tix"], s["nix"])
-        tiy = jnp.minimum(c["tiy"], s["niy"])
-        tof = jnp.minimum(c["tof"], s["nof"])
-        tkx, tky = s["nkx"], s["nky"]
-        tox = jnp.clip((tix - s["nkx"]) // s["s"] + 1, 1, s["nox"])
-        toy = jnp.clip((tiy - s["nky"]) // s["s"] + 1, 1, s["noy"])
-
-        pif = jnp.minimum(c["pif"], tif)
-        pof = jnp.minimum(c["pof"], tof)
-        pox = jnp.minimum(c["pox"], tox)
-        poy = jnp.minimum(c["poy"], toy)
-        pkx = jnp.minimum(c["pkx"], tkx)
-        pky = jnp.minimum(c["pky"], tky)
-        pb = jnp.minimum(c["pb"], s["batch"])
-
-        unroll = pif * pof * pox * poy * pkx * pky * pb
-        total_macs = c["pe_group"] * c["mac_per_group"]
-        valid_macs = unroll <= total_macs
-
-        inter = (_cdiv(s["nif"], tif) * _cdiv(s["nkx"], tkx)
-                 * _cdiv(s["nky"], tky) * _cdiv(s["nox"], tox)
-                 * _cdiv(s["noy"], toy) * _cdiv(s["nof"], tof))
-        inner = (_cdiv(tif, pif) * _cdiv(tkx, pkx) * _cdiv(tky, pky)
-                 * _cdiv(tox, pox) * _cdiv(toy, poy) * _cdiv(tof, pof))
-        batch_iters = _cdiv(s["batch"], pb)
-        compute_cycles = inter * inner * batch_iters * s["repeat"]
-
-        weight_reuse = pox * poy * pb                               # Eq. (1)
-        in_win_x = (pox - 1) * s["s"] + pkx
-        in_win_y = (poy - 1) * s["s"] + pky
-        input_reuse = jnp.maximum(
-            (pof * pkx * pky * pox * poy)
-            // jnp.maximum(in_win_x * in_win_y, 1), 1)              # Eq. (2)
-
-        num_weight = (s["nox"] * s["noy"] * s["nkx"] * s["nky"] * s["nif"]
-                      * s["nof"] * s["repeat"]).astype(jnp.float64)
-        num_input = num_weight * s["batch"]
-
-        lo = c["loop_order"]
-        spatial_tiles = _cdiv(s["nox"], tox) * _cdiv(s["noy"], toy)
-        ofm_tiles = _cdiv(s["nof"], tof)
-        ws_weight = weight_elems * 1.0
-        ws_input = (input_elems * s["batch"]
-                    * ofm_tiles).astype(jnp.float64)
-        os_weight = (weight_elems * spatial_tiles).astype(jnp.float64)
-        os_input = ws_input
-        is_weight = os_weight
-        is_input = input_elems * s["batch"] * 1.0
-
-        num_weight_eff = jnp.where(
-            lo == int(LoopOrder.PAPER),
-            num_weight / jnp.maximum(weight_reuse, 1),
-            jnp.where(lo == int(LoopOrder.WEIGHT_STATIONARY), ws_weight,
-                      jnp.where(lo == int(LoopOrder.OUTPUT_STATIONARY),
-                                os_weight, is_weight)))
-        num_input_eff = jnp.where(
-            lo == int(LoopOrder.PAPER),
-            num_input / jnp.maximum(input_reuse, 1),
-            jnp.where(lo == int(LoopOrder.WEIGHT_STATIONARY), ws_input,
-                      jnp.where(lo == int(LoopOrder.OUTPUT_STATIONARY),
-                                os_input, is_input)))
-
-        wbw = jnp.maximum(c["weight_banks_pg"] * c["pe_group"]
-                          * c["bank_width"] // bit_width, 1)
-        abw = jnp.maximum(c["act_banks_pg"] * c["pe_group"]
-                          * c["bank_width"] // bit_width, 1)
-        weight_cycles = jnp.ceil(num_weight_eff / wbw)              # Eq. (7)
-        input_cycles = jnp.ceil(num_input_eff / abw)                # Eq. (8)
-
-        total = jnp.maximum(compute_cycles,
-                            jnp.maximum(weight_cycles, input_cycles))
-
-        wbuf = (c["weight_banks_pg"] * c["pe_group"] * c["bank_height"]
-                * c["bank_width"])
-        abuf = (c["act_banks_pg"] * c["pe_group"] * c["bank_height"]
-                * c["bank_width"])
-        need_w_tile = tkx * tky * tif * tof * bit_width             # Eq. (10)
-        need_a_tile = (tix * tiy * tif + tox * toy * tof) * bit_width
-        # peaks of 0 make the floor checks vacuously true, matching the
-        # numpy path's `if peak:` guards
-        valid_buf = ((wbuf >= need_w_tile) & (abuf >= need_a_tile)
-                     & (wbuf >= peak_weight_bits)                   # Eq. (11)
-                     & (abuf >= peak_input_scaled))                 # Eq. (13)
-
-        valid = (valid_macs & valid_buf).all(axis=1)
-        total_cycles = total.sum(axis=1)
-        return (total_cycles, valid, compute_cycles, weight_cycles,
-                input_cycles, total, valid_macs & valid_buf)
-
-    kern = jax.jit(kernel)
-    _JAX_KERNEL_CACHE[bit_width] = kern
-    return kern
-
-
-def _evaluate_stream_many_jax(
-    configs: "Sequence[AccelConfig] | ConfigBatch",
-    stream: OpStream,
-    hw: HardwareConstants,
-    peak_weight_bits: int = 0,
-    peak_input_bits: int = 0,
-    with_parts: bool = True,
-) -> Tuple[np.ndarray, np.ndarray, Optional[Dict[str, np.ndarray]]]:
-    import jax
-    batch = ConfigBatch.from_configs(configs)
-    max_batch = int(stream.batch.max()) if len(stream) else 1
-    peak_input_scaled = int(peak_input_bits) * max_batch
-    # x64 keeps the int64/float64 semantics of the numpy reference (the MAC
-    # and traffic counts overflow int32 on real layers)
-    with jax.enable_x64(True):
-        kern = _jax_broadcast_kernel(int(hw.bit_width))
-        out = kern(batch.matrix, stream.field_matrix,
-                   int(peak_weight_bits), peak_input_scaled)
-        # device->host transfer only what the caller consumes: the scoring
-        # hot path (with_parts=False) skips the five [C, O] matrices
-        total_cycles, valid = np.asarray(out[0]), np.asarray(out[1])
-        parts = None
-        if with_parts:
-            comp, wc, ic, total, vops = (np.asarray(x) for x in out[2:])
-            parts = {"compute": comp, "weight": wc, "input": ic,
-                     "total": total, "valid_ops": vops}
-    return total_cycles, valid, parts
-
-
 def evaluate_stream(config: AccelConfig, stream: OpStream,
                     hw: HardwareConstants = HardwareConstants(),
                     peak_weight_bits: int = 0,
@@ -1576,12 +1148,19 @@ def performance_gops(configs: "Sequence[AccelConfig] | ConfigBatch",
     """GOPS per configuration; 0.0 where the config violates constraints
 
     (the paper plots constraint-violating configurations at 0 GOPS, Fig. 7).
-    Accepts a `ConfigBatch` for the array-native fast path; `backend="jax"`
-    routes the broadcast kernel through jit.
+    `backend="numpy"` scores with `FusedStreamScorer` where it supports the
+    stream, bit-identical to the reference; `"numpy-ref"` always takes the
+    `evaluate_stream_many` reference.
     """
+    if backend not in ("numpy", "numpy-ref"):
+        raise ValueError(f"unknown backend {backend!r}; "
+                         "expected 'numpy' or 'numpy-ref'")
+    if backend == "numpy" and FusedStreamScorer.supports(stream):
+        scorer = FusedStreamScorer(stream, hw, peak_weight_bits,
+                                   peak_input_bits)
+        return scorer.metrics(ConfigBatch.from_configs(configs).matrix)[0]
     cycles, valid, _ = evaluate_stream_many(
-        configs, stream, hw, peak_weight_bits, peak_input_bits,
-        backend=backend, with_parts=False)
+        configs, stream, hw, peak_weight_bits, peak_input_bits)
     seconds = cycles / hw.frequency_hz
     gops = np.where(valid & (cycles > 0),
                     stream.total_ops / np.maximum(seconds, 1e-30) / 1e9,

@@ -39,8 +39,8 @@ The shared Evaluator
 ====================
 
 `Evaluator` (see `evaluator.py`) scores candidate pools through the fused
-single-pass cost model (`FusedStreamScorer`, bit-identical to
-`performance_gops` + `area_many`) and memoizes in a vectorized
+single-pass cost model (`FusedStreamScorer`, bit-identical to the
+`evaluate_stream_many` reference + `area_many`) and memoizes in a vectorized
 open-addressed row cache (`rowcache.RowHashCache`: 64-bit row hashes,
 exact-key collision fallback, LRU eviction), so repeated points — across
 rounds, restarts, and even different engines sharing one evaluator — are

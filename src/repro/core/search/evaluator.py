@@ -1,7 +1,7 @@
 """Shared memoizing evaluators.
 
-`Evaluator` is the accelerator-space scorer: one batched
-`evaluate_stream_many` call (via `performance_gops`) per pool, an LRU cache
+`Evaluator` is the accelerator-space scorer: one batched fused-scorer
+call per pool, an LRU cache
 keyed by the raw canonical field bytes of each config so repeated points —
 within a run, across rounds, across restarts, across engines sharing the
 evaluator — are never re-scored.  It reproduces the pre-refactor
@@ -17,10 +17,11 @@ struct-of-arrays populations (what the engines propose) or plain
 matrix feeding an open-addressed int64 table with exact-key collision
 fallback — so probing a 4096-row pool is a handful of array ops, not a
 Python loop.  Cache misses flow through the fused scorer
-(`FusedStreamScorer`, bit-identical to `performance_gops` + `area_many`
-in one pass); `backend="jax"` routes them through the jitted kernel in
-`repro.kernels.costmodel`, and `backend="numpy-ref"` keeps the
-verbatim Eqs. (1)-(13) broadcast reference for parity testing.
+(`FusedStreamScorer`, bit-identical to the `evaluate_stream_many`
+reference + `area_many` in one pass); `backend="jax"` routes them through
+its device twin `FusedJaxScorer` in `repro.kernels.costmodel`, and
+`backend="numpy-ref"` — or a stream the fused scorers do not support —
+takes the verbatim Eqs. (1)-(13) reference.
 
 `FunctionEvaluator` wraps an arbitrary scalar scoring function (e.g. the
 compile-and-measure `CellEvaluator` of `core/autotune.py`) behind the same
@@ -87,7 +88,7 @@ class Evaluator:
 
     `evaluator(pool)` returns the [len(pool)] GOPS vector with the area
     budget applied (0.0 on violation) — identical values to scoring the pool
-    uncached, in any batch composition (`evaluate_stream_many` is row-wise
+    uncached, in any batch composition (every scorer is row-wise
     independent).
 
     Objective/constraint injection (the `repro.dse` facade): pass
@@ -155,7 +156,8 @@ class Evaluator:
     # ------------------------------------------------------- fused scorers
     def _scorer(self):
         """The fused (GOPS, area) scorer for this backend, or None when the
-        stream/backend must take the reference `performance_gops` path.
+        stream/backend must take the reference
+        (`performance_gops(backend="numpy-ref")`).
         Built once and reused — the jax variant holds the device-resident
         op tables; its compiled programs are shared per process."""
         if self._fused_ready:
@@ -193,7 +195,7 @@ class Evaluator:
                 perf = performance_gops(batch, self.stream, self.hw,
                                         self.peak_weight_bits,
                                         self.peak_input_bits,
-                                        backend=self.backend)
+                                        backend="numpy-ref")
                 areas = area_many(batch, self.hw)
         self.n_batches += 1
         self.n_scored += len(batch)

@@ -1,7 +1,8 @@
 """Fused jax scorer for the Table-1 analytical cost model.
 
 `FusedJaxScorer` is the `backend="jax"` twin of
-`repro.core.costmodel.FusedStreamScorer`: the same hoisted per-(value,
+`repro.core.costmodel.FusedStreamScorer`, and the only device
+implementation of the model's equations: the same hoisted per-(value,
 op) gather tables, uploaded to the device once per table build and
 passed, with the op-stream constants, as arguments to `fused_jax_score`.
 Per call the host does the cheap LUT coding of the pool matrix and the
@@ -200,8 +201,8 @@ def _program(key, args, nvals):
 
 class FusedJaxScorer:
     """Device-resident fused (GOPS, area) scorer, `metrics()`-compatible
-    with `FusedStreamScorer` (parity <= 1e-6 on every zoo app, gated by
-    `benchmarks/evaluator_throughput.py --parity-zoo`)."""
+    with `FusedStreamScorer` (GOPS within 1e-6 of the reference, held by
+    `tests/test_fused_eval.py` and `tests/test_config_batch.py`)."""
 
     def __init__(self, stream: OpStream, hw: HardwareConstants,
                  peak_weight_bits: int = 0, peak_input_bits: int = 0,

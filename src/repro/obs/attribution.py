@@ -3,10 +3,10 @@
 The analytical model is pitched as *explainable* — for every op you can
 say which resource (MAC array, weight-buffer bandwidth, activation-buffer
 bandwidth) bounds its latency.  `explain_config` turns one
-`(config, stream)` pair into exactly that breakdown, built on the same
-vectorized `evaluate_stream_many` kernel the search uses (reference
-path — a single-config pool never enters the gather fast path), so the
-numbers agree bit-for-bit with what the Evaluator scored.
+`(config, stream)` pair into exactly that breakdown, built on the
+`evaluate_stream_many` reference, which the Evaluator's numpy scorer
+matches bit-for-bit (its device scorer within 1e-6), so the numbers
+agree with what the Evaluator scored.
 
 `Evaluator.explain(config)` is the ergonomic entry point::
 
@@ -238,7 +238,7 @@ def explain_composition(comp, specs, hw: Optional[HardwareConstants] = None,
     `specs` are the `AppSpec`s in composition app order; `traffic` is a
     `TrafficMix` / dict / None (uniform).  Numbers agree bit-for-bit with
     `CompositionEvaluator.score_with_area` (same raw `performance_gops`
-    path, same time-shared effective-rate formula)."""
+    call, same time-shared effective-rate formula)."""
     from repro.core.costmodel import ConfigBatch, performance_gops
     from repro.dse.composition import TrafficMix, composition_score
 

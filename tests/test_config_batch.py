@@ -1,18 +1,19 @@
 """Array-native evaluation pipeline: exact equivalence to the dataclass path.
 
-The contract under test: `ConfigBatch` scoring (all backends' dispatch),
-`area_many`, `repair_for_peaks_many`, and the Evaluator's vectorized cache
-keys are *bit-identical* to the per-dataclass reference over randomized
-spaces, streams (hand-built §5.1 graphs and traced zoo apps), peaks, and
-batch compositions.  The jax backend is held to 1e-6 relative on GOPS.
+The contract under test: `ConfigBatch` scoring (the fused numpy scorer
+behind `performance_gops`), `area_many`, `repair_for_peaks_many`, and the
+Evaluator's vectorized cache keys are *bit-identical* to the
+per-dataclass `evaluate_stream_many` reference over randomized spaces,
+streams (hand-built §5.1 graphs and traced zoo apps), peaks, and batch
+compositions.  The device scorer is held to 1e-6 relative on GOPS.
 """
 
 import numpy as np
 import pytest
 
 from repro.core import apps
-from repro.core.costmodel import (AccelConfig, ConfigBatch, HardwareConstants,
-                                  Op, OpStream, area_many,
+from repro.core.costmodel import (AccelConfig, ConfigBatch, FusedStreamScorer,
+                                  HardwareConstants, Op, OpStream, area_many,
                                   evaluate_stream_many, performance_gops)
 from repro.core.multiapp import AppSpec
 from repro.core.search import (AnnealOptimizer, Evaluator, FunctionEvaluator,
@@ -82,10 +83,36 @@ def random_space(rng: np.random.Generator) -> DesignSpace:
 def assert_eval_equal(a, b, context=""):
     np.testing.assert_array_equal(a[0], b[0], err_msg=f"cycles {context}")
     np.testing.assert_array_equal(a[1], b[1], err_msg=f"valid {context}")
-    if a[2] is not None and b[2] is not None:
-        for k in a[2]:
-            np.testing.assert_array_equal(a[2][k], b[2][k],
-                                          err_msg=f"parts[{k}] {context}")
+    for k in a[2]:
+        np.testing.assert_array_equal(a[2][k], b[2][k],
+                                      err_msg=f"parts[{k}] {context}")
+
+
+def assert_fused_equal(scorer, batch, ref_gops, context=""):
+    perf, area = scorer.metrics(batch.matrix)
+    np.testing.assert_array_equal(perf, ref_gops,
+                                  err_msg=f"fused gops {context}")
+    np.testing.assert_array_equal(area, area_many(batch, scorer.hw),
+                                  err_msg=f"fused area {context}")
+
+
+def assert_gops_match_reference(batch, spec, space, context):
+    """`performance_gops` (the fused numpy scorer) and
+    `FusedStreamScorer.metrics` are bit-identical to the reference."""
+    kw = dict(peak_weight_bits=spec.peak_weight_bits,
+              peak_input_bits=spec.peak_input_bits)
+    ref = evaluate_stream_many(batch.to_configs(), spec.stream, space.hw,
+                               **kw)
+    assert_eval_equal(evaluate_stream_many(batch, spec.stream, space.hw,
+                                           **kw), ref, context)
+    ref_gops = performance_gops(batch, spec.stream, space.hw,
+                                backend="numpy-ref", **kw)
+    np.testing.assert_array_equal(
+        performance_gops(batch, spec.stream, space.hw, **kw), ref_gops,
+        err_msg=context)
+    assert_fused_equal(FusedStreamScorer(spec.stream, space.hw,
+                                         domains=space.domains, **kw),
+                       batch, ref_gops, context)
 
 
 # -------------------------------------------------------------- ConfigBatch
@@ -144,30 +171,35 @@ def test_area_many_bit_identical(space):
 
 
 def test_scoring_parity_randomized():
-    """list-of-dataclass vs ConfigBatch vs reference backend, randomized
-    streams/pools/peaks: bit-identical cycles, validity, and parts."""
+    """list-of-dataclass vs ConfigBatch vs the fused scorer against the
+    reference, randomized streams/pools/peaks: bit-identical cycles,
+    validity, parts, GOPS and area."""
     rng = np.random.default_rng(3)
     for trial in range(8):
         sp = random_space(rng)
         stream = random_stream(rng)
-        # pool sizes straddling the fast-path threshold
+        # pool sizes from one row to several fused-scorer chunks' worth
         n = int(rng.choice([1, 7, 63, 64, 65, 200]))
         idx = sp.sample_indices(rng, n)
         cfgs = sp.decode(idx)
         batch = sp.decode_batch(idx)
         pw = int(rng.integers(0, 2)) * int(rng.integers(0, 1 << 24))
         pi = int(rng.integers(0, 2)) * int(rng.integers(0, 1 << 24))
-        ref = evaluate_stream_many(cfgs, stream, HW, pw, pi,
-                                   backend="numpy-ref")
+        ref = evaluate_stream_many(cfgs, stream, HW, pw, pi)
         ctx = f"trial={trial} n={n}"
         assert_eval_equal(
-            evaluate_stream_many(cfgs, stream, HW, pw, pi), ref, ctx)
-        assert_eval_equal(
             evaluate_stream_many(batch, stream, HW, pw, pi), ref, ctx)
+        ref_gops = performance_gops(cfgs, stream, HW, pw, pi,
+                                    backend="numpy-ref")
         np.testing.assert_array_equal(
-            performance_gops(batch, stream, HW, pw, pi),
-            performance_gops(cfgs, stream, HW, pw, pi, backend="numpy-ref"),
+            performance_gops(batch, stream, HW, pw, pi), ref_gops,
             err_msg=ctx)
+        np.testing.assert_array_equal(
+            performance_gops(cfgs, stream, HW, pw, pi), ref_gops,
+            err_msg=ctx)
+        assert_fused_equal(FusedStreamScorer(stream, HW, pw, pi,
+                                             domains=sp.domains),
+                           batch, ref_gops, ctx)
 
 
 @pytest.mark.parametrize("app", ["resnet", "ptb", "wdl", "fasterRCNN"])
@@ -175,40 +207,42 @@ def test_scoring_parity_handbuilt_apps(space, app):
     spec = AppSpec.from_graph(app, apps.build_app(app))
     rng = np.random.default_rng(4)
     batch = space.decode_batch(space.sample_indices(rng, 128))
-    kw = dict(peak_weight_bits=spec.peak_weight_bits,
-              peak_input_bits=spec.peak_input_bits)
-    ref = evaluate_stream_many(batch.to_configs(), spec.stream, space.hw,
-                               backend="numpy-ref", **kw)
-    fast = evaluate_stream_many(batch, spec.stream, space.hw, **kw)
-    assert_eval_equal(fast, ref, app)
+    assert_gops_match_reference(batch, spec, space, app)
 
 
 def test_scoring_parity_traced_zoo_app(space, zoo_spec):
     rng = np.random.default_rng(5)
     batch = space.decode_batch(space.sample_indices(rng, 128))
-    kw = dict(peak_weight_bits=zoo_spec.peak_weight_bits,
-              peak_input_bits=zoo_spec.peak_input_bits)
-    ref = evaluate_stream_many(batch.to_configs(), zoo_spec.stream,
-                               space.hw, backend="numpy-ref", **kw)
-    fast = evaluate_stream_many(batch, zoo_spec.stream, space.hw, **kw)
-    assert_eval_equal(fast, ref, "zoo")
+    assert_gops_match_reference(batch, zoo_spec, space, "zoo")
 
 
 def test_jax_backend_matches_numpy(space, resnet_spec, zoo_spec):
-    """GOPS parity within 1e-6 relative (exact in practice: the jit kernel
-    runs the same int64/float64 formulas under x64)."""
-    jax = pytest.importorskip("jax")
-    del jax
+    """The device scorer's GOPS within 1e-6 relative of the reference
+    (exact in practice on the CPU: the program runs the same int64/float64
+    formulas under x64)."""
+    pytest.importorskip("jax")
+    from repro.kernels.costmodel import FusedJaxScorer
     rng = np.random.default_rng(6)
     for spec in (resnet_spec, zoo_spec):
         batch = space.decode_batch(space.sample_indices(rng, 96))
         kw = dict(peak_weight_bits=spec.peak_weight_bits,
                   peak_input_bits=spec.peak_input_bits)
-        ref = performance_gops(batch, spec.stream, space.hw, **kw)
-        jx = performance_gops(batch, spec.stream, space.hw, backend="jax",
-                              **kw)
+        ref = performance_gops(batch, spec.stream, space.hw,
+                               backend="numpy-ref", **kw)
+        jx, _ = FusedJaxScorer(spec.stream, space.hw, domains=space.domains,
+                               **kw).metrics(batch.matrix)
         rel = np.abs(jx - ref) / np.maximum(np.abs(ref), 1e-30)
         assert float(rel.max()) <= 1e-6
+
+
+@pytest.mark.parametrize("backend", ["jax", "numpy-fast"])
+def test_performance_gops_rejects_unknown_backend(space, resnet_spec,
+                                                  backend):
+    batch = space.decode_batch(space.sample_indices(
+        np.random.default_rng(8), 4))
+    with pytest.raises(ValueError, match="unknown backend"):
+        performance_gops(batch, resnet_spec.stream, space.hw,
+                         backend=backend)
 
 
 # -------------------------------------------------------------- repair parity

@@ -3,8 +3,8 @@
 The contract under test: the fused single-pass scorer
 (`FusedStreamScorer`) and the hashed row cache (`RowHashCache`) behind the
 live `Evaluator` are *bit-identical* to the reference pipeline
-(`performance_gops(backend="numpy-ref")` + `area_many` + the old
-tobytes()-keyed cache semantics) over randomized spaces, pools, batch
+(`performance_gops(backend="numpy-ref")` + `area_many` + a
+tobytes()-keyed cache) over randomized spaces, pools, batch
 compositions, in-pool duplicates — and under adversarial hashing (every
 row forced onto one hash bucket).  The jax fused scorer is held to 1e-6
 relative.  Cross-round dedup is pure bookkeeping: counts land in the
