@@ -9,7 +9,9 @@ batched Evaluator call.
 On spaces with an array decode (`decode_batch`, i.e. the accelerator
 `DesignSpace`) the whole round stays array-native: indices -> `ConfigBatch`
 -> batched `repair_for_peaks_many` -> Evaluator, with no dataclass
-materialized; the repaired population is bit-identical to the per-config
+materialized.  The repair grows the buffers in closed form over only the
+rows under a peak floor and steps the area shrink over only the rows over
+the budget; the repaired population is bit-identical to the per-config
 scalar path.
 """
 

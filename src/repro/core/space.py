@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.costmodel import (AccelConfig, ConfigBatch,
                                   HardwareConstants, LoopOrder, area_many)
 
@@ -218,80 +219,122 @@ class DesignSpace:
 
         Row `i` of the result equals
         ``repair_for_peaks(configs[i], peak_weight_bits, peak_input_bits)``
-        exactly: each phase iterates the same bounded repair schedule, but
-        one numpy mask operation per step repairs every still-unsatisfied
-        row at once instead of a Python loop per offspring.  Accepts a
-        `ConfigBatch` or any `AccelConfig` sequence; returns a new
-        `ConfigBatch` (inputs are never mutated)."""
-        batch = ConfigBatch.from_configs(configs)
-        m = batch.matrix.copy()
+        exactly.  Phases A/B grow the buffers in closed form, a few numpy
+        passes per variable over only the rows still under their floor
+        (`_grow_many`); phases C/D run the scalar shrink schedule step by
+        step, but only on the rows over the area budget after growth
+        (`_shrink_many`), written back once.  Accepts a `ConfigBatch` or
+        any `AccelConfig` sequence; returns a new `ConfigBatch` (inputs
+        are never mutated)."""
+        m = ConfigBatch.from_configs(configs).matrix.copy()
         n = m.shape[0]
-        j_of = ConfigBatch._INDEX
-
-        def wbuf(mm: np.ndarray) -> np.ndarray:
-            return (mm[:, j_of["weight_banks_pg"]] * mm[:, j_of["pe_group"]]
-                    * mm[:, j_of["bank_height"]] * mm[:, j_of["bank_width"]])
-
-        def abuf(mm: np.ndarray) -> np.ndarray:
-            return (mm[:, j_of["act_banks_pg"]] * mm[:, j_of["pe_group"]]
-                    * mm[:, j_of["bank_height"]] * mm[:, j_of["bank_width"]])
-
-        def area(mm: np.ndarray) -> np.ndarray:
-            return area_many(ConfigBatch(mm), self.hw)
-
-        # phases A/B: grow the first growable buffer variable (in order)
-        # for every row still under its peak floor
-        for grow_vars, buf, floor in ((self._GROW_W, wbuf, peak_weight_bits),
-                                      (self._GROW_A, abuf, peak_input_bits)):
-            for _ in range(64):
-                need = buf(m) < floor
-                if not need.any():
-                    break
-                bumped = np.zeros(n, dtype=bool)
-                for var in grow_vars:
-                    j, dom = j_of[var], self._sorted_domain(var)
-                    pos = np.searchsorted(dom, m[:, j], side="right")
-                    sel = need & ~bumped & (pos < len(dom))
-                    if sel.any():
-                        m[sel, j] = dom[pos[sel]]
-                        bumped |= sel
-                if not bumped.any():      # nothing growable -> scalar `break`
-                    break
-
-        # phase C: shrink compute/tiling variables while over the area budget
-        if self.area_budget > 0:
-            for var in self._SHRINK_AREA:
-                j, dom = j_of[var], self._sorted_domain(var)
-                for _ in range(len(dom)):
-                    pos = np.searchsorted(dom, m[:, j], side="left")
-                    sel = (area(m) > self.area_budget) & (pos > 0)
-                    if not sel.any():
-                        break
-                    m[sel, j] = dom[pos[sel] - 1]
-
-            # phase D: shrink buffer variables stepwise, accepting only steps
-            # that keep both Eq. 11/13 floors satisfied
-            for _ in range(64):
-                over = area(m) > self.area_budget
-                if not over.any():
-                    break
-                changed = np.zeros(n, dtype=bool)
-                for var in self._SHRINK_BUFS:
-                    j, dom = j_of[var], self._sorted_domain(var)
-                    pos = np.searchsorted(dom, m[:, j], side="left")
-                    sel = over & ~changed & (pos > 0)
-                    if not sel.any():
-                        continue
-                    cand = m[sel].copy()
-                    cand[:, j] = dom[pos[sel] - 1]
-                    ok = ((wbuf(cand) >= peak_weight_bits)
-                          & (abuf(cand) >= peak_input_bits))
-                    rows = np.flatnonzero(sel)[ok]
-                    m[rows, j] = dom[pos[rows] - 1]
-                    changed[rows] = True
-                if not changed.any():     # every over row stuck -> break
-                    break
+        with obs.span("space.repair", n=n):
+            grown = self._grow_many(m, self._GROW_W, "weight_banks_pg",
+                                    peak_weight_bits)
+            grown |= self._grow_many(m, self._GROW_A, "act_banks_pg",
+                                     peak_input_bits)
+            over = np.zeros(0, dtype=np.int64)
+            if self.area_budget > 0:
+                over = np.flatnonzero(area_many(ConfigBatch(m), self.hw)
+                                      > self.area_budget)
+                if len(over):
+                    sub = m[over]
+                    self._shrink_many(sub, peak_weight_bits, peak_input_bits)
+                    m[over] = sub
+            obs.counter("repair.rows", n)
+            obs.counter("repair.rows_grown", int(grown.sum()))
+            obs.counter("repair.rows_over_area", len(over))
         return ConfigBatch(m)
+
+    def _grow_many(self, m: np.ndarray, grow_vars: Sequence[str],
+                   banks: str, floor: int) -> np.ndarray:
+        """Phases A/B of `repair_for_peaks` on `m` in place; returns the
+        bool[N] mask of rows moved.
+
+        The scalar schedule bumps the first growable variable one domain
+        step at a time, so a variable rises until the floor holds or it
+        reaches its maximum before the next one moves: its final value is
+        the first domain value >= ceil(floor / rest), `rest` being the
+        product of the other buffer factors, clipped to the domain's
+        maximum.  A row's bumps are counted as domain positions and stop
+        at the scalar loop's 64th."""
+        j_of = ConfigBatch._INDEX
+        factors = [j_of[v] for v in (banks, "pe_group", "bank_height",
+                                     "bank_width")]
+        moved = np.zeros(m.shape[0], dtype=bool)
+        rows = np.flatnonzero(
+            m[:, factors[0]] * m[:, factors[1]] * m[:, factors[2]]
+            * m[:, factors[3]] < floor)
+        cols = [m[rows, f] for f in factors]     # copies, never views of m
+        left = np.full(len(rows), 64, dtype=np.int64)
+        big = np.iinfo(np.int64).max
+        floor64 = min(floor, big)
+        for var in grow_vars:
+            if not len(rows):
+                break
+            k, dom = factors.index(j_of[var]), self._sorted_domain(var)
+            others = [c for i, c in enumerate(cols) if i != k]
+            rest = others[0] * others[1] * others[2]
+            target = np.where(rest > 0, -(-floor64 // np.maximum(rest, 1)),
+                              big)
+            first = np.searchsorted(dom, cols[k], side="right")
+            last = np.minimum(np.searchsorted(dom, target, side="left"),
+                              len(dom) - 1)
+            steps = np.minimum(np.maximum(last - first + 1, 0), left)
+            step = np.flatnonzero(steps)
+            cols[k][step] = dom[first[step] + steps[step] - 1]
+            m[rows[step], j_of[var]] = cols[k][step]
+            moved[rows[step]] = True
+            left -= steps
+            keep = np.flatnonzero((rest * cols[k] < floor) & (left > 0))
+            rows, left = rows[keep], left[keep]
+            cols = [c[keep] for c in cols]
+        return moved
+
+    def _shrink_many(self, sub: np.ndarray, peak_weight_bits: int,
+                     peak_input_bits: int) -> None:
+        """Phases C/D of `repair_for_peaks`, in place on `sub`, the rows
+        over the area budget: the scalar step schedule, one domain
+        position per step, with `area_many` over this subset only."""
+        j_of = ConfigBatch._INDEX
+        budget = self.area_budget
+
+        def over() -> np.ndarray:
+            return area_many(ConfigBatch(sub), self.hw) > budget
+
+        # phase C: shrink compute/tiling variables while over the budget
+        for var in self._SHRINK_AREA:
+            j, dom = j_of[var], self._sorted_domain(var)
+            pos = np.searchsorted(dom, sub[:, j], side="left")
+            for _ in range(len(dom)):
+                sel = over() & (pos > 0)
+                if not sel.any():
+                    break
+                pos[sel] -= 1
+                sub[sel, j] = dom[pos[sel]]
+
+        # phase D: shrink buffer variables stepwise, accepting only steps
+        # that keep both Eq. 11/13 floors satisfied; a row with no such
+        # step leaves for good (the scalar `break`)
+        active = np.ones(len(sub), dtype=bool)
+        for _ in range(64):
+            active &= over()
+            if not active.any():
+                break
+            changed = np.zeros(len(sub), dtype=bool)
+            for var in self._SHRINK_BUFS:
+                j, dom = j_of[var], self._sorted_domain(var)
+                pos = np.searchsorted(dom, sub[:, j], side="left")
+                sel = np.flatnonzero(active & ~changed & (pos > 0))
+                if not len(sel):
+                    continue
+                cand = ConfigBatch(sub[sel])
+                cand.matrix[:, j] = dom[pos[sel] - 1]
+                ok = ((cand.weight_buffer_bits_arr() >= peak_weight_bits)
+                      & (cand.act_buffer_bits_arr() >= peak_input_bits))
+                sub[sel[ok], j] = cand.matrix[ok, j]
+                changed[sel[ok]] = True
+            active &= changed
 
 
 # A representative area budget: room for ~16K MACs plus ~tens of Mbit of

@@ -8,6 +8,8 @@ streams (hand-built §5.1 graphs and traced zoo apps), peaks, and batch
 compositions.  The device scorer is held to 1e-6 relative on GOPS.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -247,28 +249,81 @@ def test_performance_gops_rejects_unknown_backend(space, resnet_spec,
 
 # -------------------------------------------------------------- repair parity
 
-def test_repair_many_bit_identical_over_random_spaces():
+def _repair_cases():
+    """(space, [N, V] index pool, peak_weight_bits, peak_input_bits) per
+    parity case, drawn from a fixed seed."""
     rng = np.random.default_rng(7)
-    for trial in range(12):
+    cases = {"random-small": []}
+    for _ in range(12):
         sp = random_space(rng)
         idx = sp.sample_indices(rng, int(rng.integers(1, 48)))
         pw = int(rng.integers(0, 3)) * int(rng.integers(0, 1 << 26))
         pi = int(rng.integers(0, 3)) * int(rng.integers(0, 1 << 26))
+        cases["random-small"].append((sp, idx, pw, pi))
+    # the benchmark cells' real floors: qwen2.5-32b prefill and decode
+    sp = default_space()
+    for name, pw, pi in (("qwen-prefill", 0, 183541760),
+                         ("qwen-decode", 0, 3318280),
+                         ("weight-floor", 1 << 26, 3318280)):
+        cases[name] = [(sp, sp.sample_indices(rng, 4096), pw, pi)]
+    for budget in (0.0, default_space().area_budget, 30000.0):
+        cases[f"random-large-{budget:g}"] = []
+        for _ in range(3):
+            sp = dataclasses.replace(random_space(rng), area_budget=budget)
+            idx = sp.sample_indices(rng, int(rng.integers(1, 2049)))
+            pw = int(rng.integers(0, 3)) * int(rng.integers(0, 1 << 28))
+            pi = int(rng.integers(0, 3)) * int(rng.integers(0, 1 << 30))
+            cases[f"random-large-{budget:g}"].append((sp, idx, pw, pi))
+    # more than 64 bank heights: the scalar loop's 64-bump cap binds
+    base = default_space()
+    sp = DesignSpace(domains={**base.domains,
+                              "bank_height": tuple(range(16, 16 * 100, 16))},
+                     hw=base.hw, area_budget=base.area_budget)
+    cases["bump-cap"] = [(sp, sp.sample_indices(rng, 512), 1 << 30, 1 << 30),
+                         (sp, sp.sample_indices(rng, 512), 1 << 20, 1 << 25)]
+    return cases
+
+
+REPAIR_CASES = _repair_cases()
+
+
+@pytest.mark.parametrize("case", sorted(REPAIR_CASES))
+def test_repair_many_bit_identical_over_random_spaces(case):
+    for trial, (sp, idx, pw, pi) in enumerate(REPAIR_CASES[case]):
         scalar = [sp.repair_for_peaks(c, pw, pi) for c in sp.decode(idx)]
         batched = sp.repair_for_peaks_many(sp.decode_batch(idx), pw, pi)
         np.testing.assert_array_equal(
             batched.matrix, ConfigBatch.from_configs(scalar).matrix,
-            err_msg=f"trial={trial} pw={pw} pi={pi}")
+            err_msg=f"case={case} trial={trial} pw={pw} pi={pi}")
 
 
-def test_repair_many_accepts_config_sequence(space):
+def test_repair_many_cap_binds_on_a_long_domain():
+    """The bump-cap case really exercises the cap: the scalar repair stops
+    after 64 bank-height steps, short of the floor."""
+    sp, idx, pw, pi = REPAIR_CASES["bump-cap"][0]
+    cfg = sp.decode(idx[:1])[0]
+    cfg = dataclasses.replace(cfg, bank_height=16, weight_banks_pg=1,
+                              bank_width=16, pe_group=1)
+    got = sp.repair_for_peaks(cfg, pw, 0)
+    assert got.bank_height == 16 * 65
+    assert got.weight_buffer_bits() < pw
+    assert sp.repair_for_peaks_many([cfg], pw, 0).to_configs() == [got]
+
+
+@pytest.mark.parametrize("as_batch", [False, True])
+def test_repair_many_accepts_config_sequence(space, as_batch):
     rng = np.random.default_rng(8)
     cfgs = [space.sample(rng) for _ in range(9)]
-    got = space.repair_for_peaks_many(cfgs, 1 << 22, 1 << 22)
+    given = ConfigBatch.from_configs(cfgs) if as_batch else list(cfgs)
+    before = ConfigBatch.from_configs(given).matrix.copy()
+    got = space.repair_for_peaks_many(given, 1 << 22, 1 << 22)
     want = [space.repair_for_peaks(c, 1 << 22, 1 << 22) for c in cfgs]
     assert got.to_configs() == want
+    assert got.to_configs() != cfgs          # the repair did move rows
     # inputs are untouched (repair copies)
-    assert ConfigBatch.from_configs(cfgs).to_configs() == cfgs
+    np.testing.assert_array_equal(ConfigBatch.from_configs(given).matrix,
+                                  before)
+    assert ConfigBatch.from_configs(given).to_configs() == cfgs
 
 
 # ------------------------------------------------------- evaluator + engines

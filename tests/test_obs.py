@@ -255,6 +255,38 @@ def test_frontend_trace_and_scorer_width_are_counted():
     assert obs.metrics().counters["scorer.cells"] == 256 * columns
 
 
+def test_peak_repair_span_and_counters():
+    """A batched peak repair records one `space.repair` span (its row
+    count) and counts the rows in, the rows the buffer growth moved and
+    the rows over the area budget after it.  Disabled, nothing is
+    recorded, and the repaired rows are the same."""
+    space = default_space()
+    pools = [space.decode_batch(space.sample_indices(
+        np.random.default_rng(s), 512)) for s in range(3)]
+    floors = (1 << 22, 183541760)
+    plain = [space.repair_for_peaks_many(p, *floors).matrix for p in pools]
+    assert len(obs.tracer()) == 0
+    assert obs.metrics().export()["counters"] == {}
+
+    obs.enable(trace=True, metrics=True, journal=False)
+    for pool, want in zip(pools, plain):
+        got = space.repair_for_peaks_many(pool, *floors)
+        np.testing.assert_array_equal(got.matrix, want)
+    spans = [e for e in obs.tracer().export()
+             if e.get("name") == "space.repair"]
+    assert [e["args"]["n"] for e in spans] == [512] * 3
+    c = obs.metrics().counters
+    changed = sum(int((w != p.matrix).any(axis=1).sum())
+                  for p, w in zip(pools, plain))
+    assert c["repair.rows"] == 3 * 512
+    assert 0 < c["repair.rows_grown"] <= c["repair.rows"]
+    assert 0 < c["repair.rows_over_area"] <= c["repair.rows"]
+    # a grown row stays changed (the shrink never breaks a floor again);
+    # every other changed row was over the area budget
+    assert c["repair.rows_grown"] <= changed \
+        <= c["repair.rows_grown"] + c["repair.rows_over_area"]
+
+
 def test_spans_lie_on_the_profiler_host_plane(tmp_path):
     """With `jax` imported, an enabled span is also a profiler
     TraceAnnotation: the trace's host plane holds an event of the same
