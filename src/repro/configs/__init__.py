@@ -24,6 +24,7 @@ _MODULES: Dict[str, str] = {
     "whisper-medium": "repro.configs.whisper_medium",
     "olmoe-1b-7b": "repro.configs.olmoe_1b_7b",
     "deepseek-v2-lite-16b": "repro.configs.deepseek_v2_lite_16b",
+    "kimi-linear-48b-a3b": "repro.configs.kimi_linear_48b_a3b",
 }
 
 ARCH_NAMES: Tuple[str, ...] = tuple(_MODULES.keys())
@@ -42,7 +43,7 @@ def list_archs() -> List[str]:
 
 
 def cells() -> List[Tuple[str, ShapeSpec]]:
-    """All 40 (architecture x shape) cells, with applicability flags."""
+    """Every (architecture x shape) cell, with applicability flags."""
     return [(a, s) for a in ARCH_NAMES for s in SHAPES]
 
 

@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro import obs
+from repro.core.apps import build_app
 from repro.core.costmodel import (AccelConfig, ConfigBatch,
                                   HardwareConstants, OpStream,
                                   area_many, performance_gops)
@@ -314,10 +315,19 @@ class Study:
         self._app_sources: List[Optional[str]] = [
             a if isinstance(a, str) else None for a in apps]
 
-        self.specs: List[AppSpec] = [
-            a if isinstance(a, AppSpec)
-            else AppSpec.from_app(a, weight_peak_mode=weight_peak_mode)
-            for a in apps]
+        with obs.span("study.app_specs", apps=len(apps)) as args:
+            self.specs: List[AppSpec] = []
+            nodes = 0
+            for a in apps:
+                if isinstance(a, AppSpec):
+                    self.specs.append(a)
+                    continue
+                graph = build_app(a)
+                nodes += len(graph.nodes)
+                self.specs.append(AppSpec.from_graph(
+                    a, graph, weight_peak_mode=weight_peak_mode))
+            if args is not None:
+                args.update(nodes=nodes)
         if not self.specs and evaluator is None:
             raise ValueError("a Study needs apps=... or evaluator=...")
         if evaluator is not None:

@@ -28,15 +28,24 @@ min(E, T*k) expert instances of ceil(T*k / E) rows.  A decode step is
 top_k matvec instances (only the routed experts' weights stream), a
 128-token prefill over 64 experts at top-6 is 64 instances of 12 rows.
 
+A linear-attention layer's decode state is fixed in size (kimi-linear's
+KDA: a [heads, dk, dv] matrix and the short conv's last 3 inputs per
+layer); it is an activation vertex of the decode step like a KV cache, so
+the Eq. 13 floor sees it.
+
 Graphs are memoized per process; listing `ZOO_APP_NAMES` costs nothing.
 Tracing an app records a `frontend.trace` span (args `app`, `ops`,
-`columns`: its unique op columns) and, for MoE models, the counter
-`frontend.expert_instances` while `repro.obs` is enabled.
+`columns`: its unique op columns), for MoE models the counter
+`frontend.expert_instances`, and for a decode app with recurrent state the
+counter `frontend.state_bits` (its bits at the traced width: every
+decoder cache entry without a "kv_seq" axis) while `repro.obs` is
+enabled.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Dict, Tuple
 
 import jax
@@ -45,8 +54,8 @@ import jax.numpy as jnp
 from repro import obs
 from repro.configs import ARCH_NAMES, get_arch
 from repro.core.graph import ComputationGraph
-from repro.frontend.trace import trace_to_graph
-from repro.models.layers import Runtime, spec_shapes
+from repro.frontend.trace import DEFAULT_BIT_WIDTH, trace_to_graph
+from repro.models.layers import Runtime, Spec, spec_shapes
 
 __all__ = ["ZOO_APP_NAMES", "ZOO_VARIANTS", "build_zoo_app",
            "PREFILL_SEQ", "DECODE_CACHE"]
@@ -116,7 +125,14 @@ def _decode_graph(arch_name: str) -> ComputationGraph:
     else:
         from repro.models.lm import DecoderLM
         model = DecoderLM(arch)
-    cache = spec_shapes(model.cache_specs(1, DECODE_CACHE), jnp.bfloat16)
+    specs = model.cache_specs(1, DECODE_CACHE)
+    state = 0 if arch.is_encdec else sum(     # (whisper's cross-KV is not)
+        math.prod(s.shape) for s in jax.tree.leaves(
+            specs, is_leaf=lambda s: isinstance(s, Spec))
+        if "kv_seq" not in s.axes)
+    if state:
+        obs.counter("frontend.state_bits", state * DEFAULT_BIT_WIDTH)
+    cache = spec_shapes(specs, jnp.bfloat16)
     token = _sds((1, 1))
     pos = _sds(())
 

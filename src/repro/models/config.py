@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["MoEConfig", "MLAConfig", "ArchConfig"]
+__all__ = ["MoEConfig", "MLAConfig", "KDAConfig", "ArchConfig"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +29,8 @@ class MoEConfig:
     router_dtype: str = "float32"
     first_dense: int = 0           # leading dense layers (deepseek-v2)
     dense_d_ff: int = 0            # FF width of those dense layers
+    scoring: str = "softmax"       # router scores: softmax | sigmoid
+    routed_scale: float = 1.0      # routed_scaling_factor on the gates
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +42,20 @@ class MLAConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     q_lora_rank: int = 0           # 0 = full-rank queries (V2-Lite)
+    use_rope: bool = True          # False: the decoupled slice is unrotated
+
+
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention: a gated delta rule with per-channel decay
+    (arXiv:2510.26692).  `layers` are the 1-based indices of the KDA
+    layers; every other layer is full (MLA) attention."""
+
+    num_heads: int
+    head_dim: int                  # key and value width per head
+    layers: Tuple[int, ...]
+    gate_rank: int                 # rank of the decay and output gates
+    conv_size: int = 4             # short causal conv on q, k and v
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +83,7 @@ class ArchConfig:
 
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
+    kda: Optional[KDAConfig] = None
 
     # encoder-decoder (audio family)
     encoder_layers: int = 0
@@ -88,9 +105,30 @@ class ArchConfig:
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
 
-    def pattern_for(self, n_layers: int) -> Tuple[str, ...]:
+    def layer_plan(self) -> Tuple[Tuple[str, str], ...]:
+        """(mixer, ffn) of every layer.  The mixer is the block pattern's
+        kind ("mla" for attention with `mla` set; "kda" at `kda.layers`),
+        cycled from the first layer after the leading dense ones; the ffn
+        is "dense", "moe" or "none" (mLSTM blocks have none)."""
+        lead = self.moe.first_dense if self.moe is not None else 0
+        kda = set(self.kda.layers) if self.kda is not None else ()
         p = self.block_pattern
-        return tuple(p[i % len(p)] for i in range(n_layers))
+        plan = []
+        for i in range(self.num_layers):
+            mixer = "attn" if i < lead else p[(i - lead) % len(p)]
+            if i + 1 in kda:
+                mixer = "kda"
+            elif mixer == "attn" and self.mla is not None:
+                mixer = "mla"
+            if mixer == "mlstm":
+                ffn = "none"
+            elif (self.moe is not None and i >= lead
+                  and mixer in ("attn", "mla", "kda")):
+                ffn = "moe"
+            else:
+                ffn = "dense"
+            plan.append((mixer, ffn))
+        return tuple(plan)
 
     def param_count(self) -> int:
         """Analytic parameter count (embeddings + blocks + norms)."""
@@ -127,26 +165,34 @@ class ArchConfig:
             return d * up * 2 + up * d + 3 * up * (up // max(nh, 1)) // max(
                 up // max(nh, 1), 1)  # approx q,k,v projections
 
-        for kind in self.pattern_for(self.num_layers):
-            if kind in ("attn", "local_attn"):
+        def kda_params() -> int:
+            k = self.kda
+            w, r = k.num_heads * k.head_dim, k.gate_rank
+            return (3 * d * w + k.conv_size * 3 * w       # W_qkv, conv
+                    + 2 * (d * r + r * w)                 # decay, out gates
+                    + d * k.num_heads + w * d)            # W_beta, W_o
+
+        for mixer, ffn in self.layer_plan():
+            if mixer in ("attn", "mla", "local_attn"):
                 total += attn_params()
-                if self.moe is not None:
-                    m = self.moe
-                    total += d * m.num_experts                 # router
-                    total += m.num_experts * mlp_params(m.d_expert) // 1
-                    if m.num_shared:
-                        total += mlp_params(m.d_expert * m.num_shared)
-                elif self.d_ff:
-                    total += mlp_params(self.d_ff)
-            elif kind == "rglru":
+            elif mixer == "kda":
+                total += kda_params()
+            elif mixer == "rglru":
                 total += rglru_params()
-                if self.d_ff:
-                    total += mlp_params(self.d_ff)
-            elif kind in ("mlstm", "slstm"):
+            else:
                 total += mlstm_params()
+            if ffn == "moe":
+                m = self.moe
+                total += d * m.num_experts                 # router
+                total += m.num_experts * mlp_params(m.d_expert)
+                if m.num_shared:
+                    total += mlp_params(m.d_expert * m.num_shared)
+            elif ffn == "dense" and mixer != "slstm":
+                total += mlp_params(self.moe.dense_d_ff if self.moe
+                                    else self.d_ff)
         if self.encoder_layers:
             # encoder: self-attn + MLP; decoder layers already counted via
-            # the pattern loop get their cross-attention added here
+            # the layer plan get their cross-attention added here
             total += self.encoder_layers * (attn_params()
                                             + mlp_params(self.d_ff))
             total += self.num_layers * attn_params()      # cross-attn

@@ -1,6 +1,7 @@
 """Model-zoo layers: GQA/MLA attention, SwiGLU/GELU MLPs, dropless
-token-choice MoE, RG-LRU recurrent blocks, mLSTM/sLSTM blocks, local
-(sliding-window) attention — all as pure functions over parameter pytrees.
+token-choice MoE, RG-LRU recurrent blocks, mLSTM/sLSTM blocks, KDA (Kimi
+Delta Attention) linear attention, local (sliding-window) attention — all
+as pure functions over parameter pytrees.
 
 Conventions
 -----------
@@ -70,7 +71,8 @@ class Runtime:
 class Spec:
     shape: Tuple[int, ...]
     axes: Tuple[Optional[str], ...]
-    init: str = "normal"            # normal | zeros | ones | rglru_a | small
+    # normal | zeros | ones | rglru_a | kda_a_log | conv | small
+    init: str = "normal"
     dtype: Optional[str] = None     # None -> param_dtype; "bf16" | "f32"
 
     def __post_init__(self):
@@ -104,6 +106,15 @@ def init_params(specs: PyTree, key: jax.Array,
             u = jax.random.uniform(k, spec.shape, jnp.float32,
                                    0.9 ** 2, 0.999 ** 2)
             p = (jnp.log(u) - jnp.log1p(-u)).astype(dt)
+        elif spec.init == "kda_a_log":
+            # KDA decay rates: A = exp(A_log) ~ U(1, 16), as fla initialises
+            p = jnp.log(jax.random.uniform(k, spec.shape, jnp.float32,
+                                           1.0, 16.0)).astype(dt)
+        elif spec.init == "conv":
+            # a short conv's taps ~ U(+-1/sqrt(K)), PyTorch's Conv1d default
+            bound = 1.0 / math.sqrt(spec.shape[0])
+            p = jax.random.uniform(k, spec.shape, jnp.float32, -bound,
+                                   bound).astype(dt)
         else:
             scale = 0.02 if spec.init == "normal" else 0.006
             fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
@@ -451,9 +462,10 @@ def mla_specs(d: int, n_heads: int, kv_lora: int, nope: int, rope_d: int,
 
 def mla_attention_train(p: Params, x: jax.Array, *, n_heads: int,
                         kv_lora: int, nope: int, rope_d: int, v_hd: int,
-                        rope_theta: float, eps: float,
-                        rt: Runtime) -> jax.Array:
-    """Multi-head latent attention, expanded (training) form."""
+                        rope_theta: float, eps: float, rt: Runtime,
+                        rope: bool = True) -> jax.Array:
+    """Multi-head latent attention, expanded (training) form.  With
+    `rope` False the decoupled `rope_d` slice is kept but not rotated."""
     cd = rt.compute_dtype
     B, S, _ = x.shape
     q = jnp.einsum("bsd,df->bsf", x, p["wq"].astype(cd),
@@ -472,10 +484,11 @@ def mla_attention_train(p: Params, x: jax.Array, *, n_heads: int,
     kv = kv.reshape(B, S, n_heads, nope + v_hd)
     k_nope, v = kv[..., :nope], kv[..., nope:]
 
-    pos = jnp.arange(S)[None, :]
-    cos, sin = rope_cos_sin(pos, rope_d, rope_theta)
-    q_rope = apply_rope(q_rope, cos, sin)
-    k_rope = apply_rope(k_rope.astype(cd)[:, :, None, :], cos, sin)
+    k_rope = k_rope.astype(cd)[:, :, None, :]
+    if rope:
+        cos, sin = rope_cos_sin(jnp.arange(S)[None, :], rope_d, rope_theta)
+        q_rope = apply_rope(q_rope, cos, sin)
+        k_rope = apply_rope(k_rope, cos, sin)
     k_rope_b = jnp.broadcast_to(k_rope, (B, S, n_heads, rope_d))
 
     qf = jnp.concatenate([q_nope, q_rope], -1)
@@ -492,8 +505,8 @@ def mla_attention_train(p: Params, x: jax.Array, *, n_heads: int,
 def mla_attention_decode(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                          pos: jax.Array, *, n_heads: int, kv_lora: int,
                          nope: int, rope_d: int, v_hd: int,
-                         rope_theta: float, eps: float,
-                         rt: Runtime) -> Tuple[jax.Array, Dict]:
+                         rope_theta: float, eps: float, rt: Runtime,
+                         rope: bool = True) -> Tuple[jax.Array, Dict]:
     """Weight-absorbed MLA decode: the cache stores only the compressed
     latent (kv_lora + rope_d per token) — MLA's production memory win."""
     cd = rt.compute_dtype
@@ -502,14 +515,17 @@ def mla_attention_decode(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                    preferred_element_type=jnp.float32).astype(cd)
     q = q.reshape(B, 1, n_heads, nope + rope_d)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    cos, sin = rope_cos_sin(jnp.full((1, 1), pos), rope_d, rope_theta)
-    q_rope = apply_rope(q_rope, cos, sin)
+    if rope:
+        cos, sin = rope_cos_sin(jnp.full((1, 1), pos), rope_d, rope_theta)
+        q_rope = apply_rope(q_rope, cos, sin)
 
     ckv = jnp.einsum("bsd,df->bsf", x, p["wdkv"].astype(cd),
                      preferred_element_type=jnp.float32)
     c_new, kr_new = ckv[..., :kv_lora], ckv[..., kv_lora:]
     c_new = rms_norm(c_new.astype(cd), p["kv_norm"], eps)
-    kr_new = apply_rope(kr_new.astype(cd)[:, :, None, :], cos, sin)[:, :, 0]
+    kr_new = kr_new.astype(cd)
+    if rope:
+        kr_new = apply_rope(kr_new[:, :, None, :], cos, sin)[:, :, 0]
 
     c_cache = kv_cache_write(cache["ckv"], c_new, pos, rt)
     r_cache = kv_cache_write(cache["krope"], kr_new, pos, rt)
@@ -603,20 +619,29 @@ def moe_specs(d: int, n_experts: int, d_expert: int,
 
 
 def _route_experts(p: Params, xf: jax.Array, lo, *, top_k: int,
-                   normalize_gates: bool, cd) -> jax.Array:
+                   normalize_gates: bool, cd, scoring: str = "softmax",
+                   scale: float = 1.0) -> jax.Array:
     """Dropless routed experts of tokens xf [T, D], where the weights in
     `p` hold experts [lo, lo + E_local) of the router's; slots routed to
-    other experts add nothing.  The slots held here are sorted by expert and the three
-    expert GEMMs run as `jax.lax.ragged_dot` with the group sizes the
-    routing gives, so no expert has a capacity and no buffer is padded."""
+    other experts add nothing.  Scores are the router's softmax or its
+    sigmoid; the top-k gates are renormalised when `normalize_gates`, then
+    multiplied by `scale` (routed_scaling_factor).  The slots held here
+    are sorted by expert and the three expert GEMMs run as
+    `jax.lax.ragged_dot` with the group sizes the routing gives, so no
+    expert has a capacity and no buffer is padded."""
     T, D = xf.shape
     n_local = p["we1"].shape[0]
     logits = jnp.einsum("td,de->te", xf, p["router"].astype(cd),
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
     gate, eidx = jax.lax.top_k(probs, top_k)          # [T, k]
     if normalize_gates:
         gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gate = gate * scale
 
     e_flat = eidx.reshape(-1) - lo                    # [T*k] local expert
     held = (e_flat >= 0) & (e_flat < n_local)
@@ -644,7 +669,8 @@ def _route_experts(p: Params, xf: jax.Array, lo, *, top_k: int,
 
 
 def moe_block(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
-              normalize_gates: bool, rt: Runtime) -> jax.Array:
+              normalize_gates: bool, rt: Runtime, scoring: str = "softmax",
+              scale: float = 1.0) -> jax.Array:
     """Token-choice top-k MoE, dropless (every routed token is computed).
 
     On a mesh the block is a `shard_map`: each device routes the tokens of
@@ -658,7 +684,8 @@ def moe_block(p: Params, x: jax.Array, *, n_experts: int, top_k: int,
     assert p["router"].shape[1] == n_experts, (p["router"].shape, n_experts)
     routed = {k: p[k] for k in ("router", "we1", "we3", "we2")}
     route = functools.partial(_route_experts, top_k=top_k,
-                              normalize_gates=normalize_gates, cd=cd)
+                              normalize_gates=normalize_gates, cd=cd,
+                              scoring=scoring, scale=scale)
     if rt.mesh is None or rt.rules is None:
         y = route(routed, x.reshape(B * S, D), 0).reshape(B, S, D)
     else:
@@ -718,17 +745,22 @@ def _rglru_gates(p: Params, xb: jax.Array, n_heads: int) -> Tuple[jax.Array,
     return r, i
 
 
-def _causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array,
+def _causal_conv1d(x: jax.Array, w: jax.Array,
+                   b: Optional[jax.Array] = None,
                    prefix: Optional[jax.Array] = None) -> jax.Array:
-    """Depthwise causal conv over seq; x [B,S,W], w [K,W].  `prefix`
-    [B,K-1,W] supplies decode-time history."""
-    K = w.shape[0]
+    """Depthwise causal conv over seq; x [B,S,W], w [K,W]: a grouped
+    `conv_general_dilated` (one group per channel, costed as Table 1's
+    depthwise row), left-padded with `prefix` [B,K-1,W], the decode-time
+    history, or zeros."""
+    K, W = w.shape
     if prefix is None:
-        prefix = jnp.zeros((x.shape[0], K - 1, x.shape[2]), x.dtype)
-    xp = jnp.concatenate([prefix, x], axis=1)
-    out = sum(xp[:, i:i + x.shape[1]] * w[i].astype(x.dtype)
-              for i in range(K))
-    return out + b.astype(x.dtype)
+        prefix = jnp.zeros((x.shape[0], K - 1, W), x.dtype)
+    xp = jnp.concatenate([prefix.astype(x.dtype), x], axis=1)
+    out = jax.lax.conv_general_dilated(
+        xp, w.astype(x.dtype)[:, None, :], window_strides=(1,),
+        padding="VALID", dimension_numbers=("NWC", "WIO", "NWC"),
+        feature_group_count=W)
+    return out if b is None else out + b.astype(x.dtype)
 
 
 def rglru_block_train(p: Params, x: jax.Array, *, n_heads: int,
@@ -784,6 +816,205 @@ def rglru_block_decode(p: Params, x: jax.Array, state: Dict[str, jax.Array],
                    preferred_element_type=jnp.float32)
     new_state = {"h": h, "conv": conv_hist[:, 1:]}
     return rt.shard(y.astype(cd), "batch", None, "act_embed"), new_state
+
+
+# ===================================================================== KDA
+
+def kda_specs(d: int, n_heads: int, hd: int, conv: int,
+              rank: int) -> Dict[str, Spec]:
+    """Kimi Delta Attention (arXiv:2510.26692; fla-org `KimiDeltaAttention`):
+    fused q/k/v projection with its short conv, a low-rank per-channel
+    decay, a per-head write strength beta, and a low-rank sigmoid output
+    gate over a per-head RMSNorm."""
+    w = n_heads * hd
+    return {
+        "wqkv": Spec((d, 3 * w), ("embed", "qkv_fused")),
+        "conv_w": Spec((conv, 3 * w), (None, "qkv_fused"), "conv"),
+        "wf_down": Spec((d, rank), ("embed", None)),
+        "wf_up": Spec((rank, w), (None, "qkv_fused")),
+        "dt_bias": Spec((w,), ("qkv_fused",), "zeros"),
+        "a_log": Spec((n_heads,), (None,), "kda_a_log"),
+        "wb": Spec((d, n_heads), ("embed", None)),
+        "wg_down": Spec((d, rank), ("embed", None)),
+        "wg_up": Spec((rank, w), (None, "qkv_fused")),
+        "g_bias": Spec((w,), ("qkv_fused",), "zeros"),
+        "o_norm": Spec((hd,), (None,), "ones"),
+        "wo": Spec((w, d), ("qkv_fused", "embed")),
+    }
+
+
+def _l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
+                             + eps)
+
+
+def _kda_inputs(p: Params, x: jax.Array, prefix: Optional[jax.Array],
+                n_heads: int, hd: int, rt: Runtime):
+    """q, k, v [B,S,H,hd] (SiLU of the short conv; q and k L2-normalised
+    per head, q scaled by hd^-1/2), the log-decay g [B,S,H,hd] <= 0, beta
+    [B,S,H], and the conv's input (the decode cache keeps its tail)."""
+    cd = rt.compute_dtype
+    B, S, _ = x.shape
+    f32 = jnp.float32
+    qkv = jnp.einsum("bsd,df->bsf", x, p["wqkv"].astype(cd),
+                     preferred_element_type=f32)
+    act = jax.nn.silu(_causal_conv1d(qkv, p["conv_w"], prefix=prefix))
+    q, k, v = (t.reshape(B, S, n_heads, hd)
+               for t in jnp.split(act, 3, axis=-1))
+    q = _l2norm(q) * (1.0 / math.sqrt(hd))
+    k = _l2norm(k)
+    f = jnp.einsum("bsr,rf->bsf",
+                   jnp.einsum("bsd,dr->bsr", x, p["wf_down"].astype(cd),
+                              preferred_element_type=f32).astype(cd),
+                   p["wf_up"].astype(cd), preferred_element_type=f32)
+    f = (f + p["dt_bias"].astype(f32)).reshape(B, S, n_heads, hd)
+    g = -jnp.exp(p["a_log"].astype(f32))[:, None] * jax.nn.softplus(f)
+    beta = jax.nn.sigmoid(jnp.einsum("bsd,dh->bsh", x, p["wb"].astype(cd),
+                                     preferred_element_type=f32))
+    return q, k, v, g, beta, qkv
+
+
+def _kda_out(p: Params, o: jax.Array, x: jax.Array, eps: float,
+             rt: Runtime) -> jax.Array:
+    """y = W_o [RMSNorm_head(o) * sigmoid(W_g_up W_g_down x + b)]."""
+    cd = rt.compute_dtype
+    B, S, H, hd = o.shape
+    f32 = jnp.float32
+    gate = jnp.einsum("bsr,rf->bsf",
+                      jnp.einsum("bsd,dr->bsr", x, p["wg_down"].astype(cd),
+                                 preferred_element_type=f32).astype(cd),
+                      p["wg_up"].astype(cd), preferred_element_type=f32)
+    gate = jax.nn.sigmoid(gate + p["g_bias"].astype(f32))
+    o = rms_norm(o.astype(f32), p["o_norm"], eps).reshape(B, S, H * hd)
+    y = jnp.einsum("bsf,fd->bsd", (o * gate).astype(cd), p["wo"].astype(cd),
+                   preferred_element_type=f32)
+    return rt.shard(y.astype(cd), "batch", None, "act_embed")
+
+
+KDA_CHUNK = 64          # tokens per chunk of the prefill form (fla's too)
+
+
+def _kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                 beta: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Chunked (WY) form of the KDA recurrence
+
+        S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T,
+        o_t = S_t^T q_t
+
+    over q, k, g [B,S,H,dk], v [B,S,H,dv], beta [B,S,H] from a zero
+    state; returns o [B,S,H,dv] and the last state [B,H,dk,dv].
+
+    Per chunk of C = KDA_CHUNK tokens, with G the within-chunk cumulative
+    log-decay (G <= 0, decreasing), S_i = e^G_i * S_0 + sum_{j<=i}
+    (e^{G_i-G_j} k_j) w_j^T, where the pseudo-values w solve
+    (I + diag(b) A) w = b v - b (e^G k) S_0 and A_ij = sum_c k_ic k_jc e^{G_ic-G_jc} (j < i): w =
+    T b v - T b (e^G k) S_0 with T the unit-lower-triangular inverse.
+    No exponent is positive (with g down to -100 per token, e^{-G}
+    overflows float32 within a chunk): A and the output scores P (same
+    form with q_i, j <= i) are built from 16-token sub-chunks, each pair
+    within a sub-chunk decayed by e^{G_i-G_j}, i >= j, and each earlier
+    key decayed to the position before the query's sub-chunk, the query
+    from there.  Every step whose work grows as C*d or C^2*d is an einsum;
+    only the C x C triangular inverse (C^3/6 per chunk and head) is not.
+    Padded tail positions have k = v = g = b = 0: no write, no decay."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    C, sub = KDA_CHUNK, 16
+    ns = C // sub
+    nc = -(-S // C)
+    pad = nc * C - S
+    if pad:
+        def padded(t):
+            return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        q, k, v, g, beta = map(padded, (q, k, v, g, beta))
+
+    def chunks(t):              # [B, nc*C, H, ...] -> [nc, B, H, C, ...]
+        t = t.astype(f32).reshape((B, nc, C) + t.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 3, 2)
+
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    strict = jnp.tril(jnp.ones((C, C), bool), -1)
+    same_block = (jnp.arange(ns)[:, None, None, None]
+                  == jnp.arange(ns)[None, None, :, None])   # [ns,1,ns,1]
+    before = (jnp.arange(C)[None, :] < (jnp.arange(ns) * sub)[:, None])
+
+    def step(S0, blk):
+        qc, kc, vc, gc, bc = blk          # [B,H,C,d]; bc [B,H,C,1]
+        G = jnp.cumsum(gc, axis=2)
+        Gs = G.reshape(B, H, ns, sub, dk)
+        ks = kc.reshape(B, H, ns, sub, dk)
+        qs = qc.reshape(B, H, ns, sub, dk)
+        # pairs inside a sub-chunk: e^{G_i - G_j}, i >= j (i < j reads
+        # e^0 here and is masked out of the C x C matrices below)
+        rel = jnp.minimum(Gs[:, :, :, :, None, :] - Gs[:, :, :, None, :, :],
+                          0.0)
+        k_rel = ks[:, :, :, None, :, :] * jnp.exp(rel)    # [B,H,ns,i,j,dk]
+        a_in = jnp.einsum("bhnic,bhnijc->bhnij", ks, k_rel)
+        p_in = jnp.einsum("bhnic,bhnijc->bhnij", qs, k_rel)
+        # earlier sub-chunks through the position before the query's own
+        # (later keys read e^0 and are replaced by the pairs above)
+        ref = jnp.concatenate([jnp.zeros_like(Gs[:, :, :1, 0]),
+                               Gs[:, :, :-1, -1]], axis=2)  # [B,H,ns,dk]
+        to_ref = jnp.exp(Gs - ref[:, :, :, None, :])
+        k_dec = kc[:, :, None] * jnp.exp(jnp.minimum(
+            ref[:, :, :, None, :] - G[:, :, None, :, :], 0.0))
+        a_out = jnp.einsum("bhnic,bhnjc->bhnij", ks * to_ref, k_dec)
+        p_out = jnp.einsum("bhnic,bhnjc->bhnij", qs * to_ref, k_dec)
+
+        def whole(inner, outer, mask):    # -> [B,H,C,C]
+            diag = (inner[:, :, :, :, None, :] * same_block).reshape(
+                B, H, ns, sub, C)
+            full = jnp.where(before[:, None, :], outer, diag)
+            return jnp.where(mask, full.reshape(B, H, C, C), 0.0)
+
+        a = bc * whole(a_in, a_out, strict)
+        p = whole(p_in, p_out, lower)
+        eye = jnp.eye(C, dtype=f32)
+        t = jax.lax.linalg.triangular_solve(
+            eye + a, jnp.broadcast_to(eye, a.shape), left_side=True,
+            lower=True, unit_diagonal=True)
+        decay = jnp.exp(G)
+        u = jnp.einsum("bhij,bhjv->bhiv", t, bc * vc)
+        w_k = jnp.einsum("bhij,bhjc->bhic", t, bc * decay * kc)
+        w = u - jnp.einsum("bhic,bhcv->bhiv", w_k, S0)
+        o = (jnp.einsum("bhic,bhcv->bhiv", decay * qc, S0)
+             + jnp.einsum("bhij,bhjv->bhiv", p, w))
+        k_end = kc * jnp.exp(G[:, :, -1:] - G)
+        S1 = (jnp.exp(G[:, :, -1])[..., None] * S0
+              + jnp.einsum("bhic,bhiv->bhcv", k_end, w))
+        return S1, o
+
+    last, o = jax.lax.scan(jax.checkpoint(step),
+                           jnp.zeros((B, H, dk, dv), f32),
+                           tuple(map(chunks, (q, k, v, g, beta[..., None]))))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)     # [B, nc, C, H, dv]
+    return o.reshape(B, nc * C, H, dv)[:, :S], last
+
+
+def kda_block_train(p: Params, x: jax.Array, *, n_heads: int, hd: int,
+                    eps: float, rt: Runtime) -> jax.Array:
+    """KDA over a whole sequence in the chunked form."""
+    q, k, v, g, beta, _ = _kda_inputs(p, x, None, n_heads, hd, rt)
+    o, _ = _kda_chunked(q, k, v, g, beta)
+    return _kda_out(p, o, x, eps, rt)
+
+
+def kda_block_decode(p: Params, x: jax.Array, state: Dict[str, jax.Array],
+                     *, n_heads: int, hd: int, eps: float, rt: Runtime
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One token through the recurrence; state = {"S": [B,H,dk,dv],
+    "conv": [B,K-1,3*H*hd]} fp32.  The rank-1 write k (x) u is an einsum,
+    dk*dv MACs per head; the decay is elementwise."""
+    q, k, v, g, beta, qkv = _kda_inputs(p, x, state["conv"], n_heads, hd,
+                                        rt)
+    q, k, v, g, beta = q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+    S = state["S"] * jnp.exp(g)[..., None]
+    u = beta[..., None] * (v - jnp.einsum("bhk,bhkv->bhv", k, S))
+    S = S + jnp.einsum("bhk,bhv->bhkv", k, u)
+    o = jnp.einsum("bhk,bhkv->bhv", q, S)[:, None]
+    hist = jnp.concatenate([state["conv"], qkv], axis=1)[:, 1:]
+    return _kda_out(p, o, x, eps, rt), {"S": S, "conv": hist}
 
 
 # =================================================================== mLSTM

@@ -8,8 +8,9 @@ stacked along a leading `repeats` axis.  This keeps the lowered HLO small
 the structure XLA's latency-hiding scheduler pipelines best.
 
 Supports: dense GQA (qwen2*, mistral-nemo), MoE (olmoe), MLA+MoE
-(deepseek-v2-lite), RG-LRU hybrid (recurrentgemma), xLSTM (mlstm+slstm),
-and VLM stubs (internvl2: patch-embedding prefix).
+(deepseek-v2-lite), KDA+MLA+MoE (kimi-linear), RG-LRU hybrid
+(recurrentgemma), xLSTM (mlstm+slstm), and VLM stubs (internvl2:
+patch-embedding prefix).
 """
 
 from __future__ import annotations
@@ -34,9 +35,10 @@ __all__ = ["DecoderLM", "Group"]
 
 @dataclasses.dataclass(frozen=True)
 class Group:
-    """A scan group: `unit` (tuple of block kinds) repeated `repeats` times."""
+    """A scan group: `unit` (a tuple of (mixer, ffn) block kinds, see
+    `ArchConfig.layer_plan`) repeated `repeats` times."""
 
-    unit: Tuple[str, ...]
+    unit: Tuple[Tuple[str, str], ...]
     repeats: int
 
 
@@ -60,17 +62,27 @@ def cross_entropy(logits: jax.Array, targets: jax.Array,
 
 
 def plan_groups(cfg: ArchConfig) -> List[Group]:
-    n = cfg.num_layers
+    """Scan groups over `cfg.layer_plan()`: the leading dense layers as
+    one unit, then units of the block pattern (of a KDA hybrid: each run
+    of KDA layers with the full-attention layer that closes it), equal
+    neighbouring units stacked into one group."""
+    plan = cfg.layer_plan()
+    lead = cfg.moe.first_dense if cfg.moe is not None else 0
+    rest = plan[lead:]
+    if cfg.kda is not None:
+        cuts = [i + 1 for i, (mixer, _) in enumerate(rest) if mixer != "kda"]
+    else:
+        cuts = list(range(len(cfg.block_pattern), len(rest),
+                          len(cfg.block_pattern)))
+    bounds = [0] + [c for c in cuts if c < len(rest)] + [len(rest)]
+    units = [plan[:lead]] if lead else []
+    units += [rest[a:b] for a, b in zip(bounds, bounds[1:])]
     groups: List[Group] = []
-    if cfg.moe is not None and cfg.moe.first_dense:
-        groups.append(Group(("attn_dense",) * cfg.moe.first_dense, 1))
-        n -= cfg.moe.first_dense
-    unit = cfg.block_pattern
-    r, rem = divmod(n, len(unit))
-    if r:
-        groups.append(Group(unit, r))
-    if rem:
-        groups.append(Group(unit[:rem], 1))
+    for u in units:
+        if groups and groups[-1].unit == u:
+            groups[-1] = Group(u, groups[-1].repeats + 1)
+        else:
+            groups.append(Group(u, 1))
     return groups
 
 
@@ -80,103 +92,115 @@ def _slstm_ff_dim(d: int) -> int:
     return -(-int(4 * d / 3) // 128) * 128
 
 
-def block_specs(cfg: ArchConfig, kind: str) -> Dict[str, Any]:
+def block_specs(cfg: ArchConfig, kind: Tuple[str, str]) -> Dict[str, Any]:
+    mixer, ffn = kind
     d, hd = cfg.d_model, cfg.resolved_head_dim
     s: Dict[str, Any] = {"ln1": Spec((d,), ("embed",), "ones")}
-    if kind in ("attn", "attn_dense", "local_attn"):
-        if cfg.mla is not None:
-            m = cfg.mla
-            s["attn"] = L.mla_specs(d, cfg.num_heads, m.kv_lora_rank,
-                                    m.qk_nope_head_dim, m.qk_rope_head_dim,
-                                    m.v_head_dim)
-        else:
-            s["attn"] = L.gqa_specs(d, cfg.num_heads, cfg.num_kv_heads, hd,
-                                    cfg.qkv_bias)
-        s["ln2"] = Spec((d,), ("embed",), "ones")
-        if kind == "attn_dense":
-            ff = cfg.moe.dense_d_ff if cfg.moe else cfg.d_ff
-            s["mlp"] = L.swiglu_specs(d, ff)
-        elif cfg.moe is not None:
-            s["moe"] = L.moe_specs(d, cfg.moe.num_experts, cfg.moe.d_expert,
-                                   cfg.moe.num_shared)
-        else:
-            s["mlp"] = L.swiglu_specs(d, cfg.d_ff)
-    elif kind == "rglru":
+    if mixer == "mla":
+        m = cfg.mla
+        s["attn"] = L.mla_specs(d, cfg.num_heads, m.kv_lora_rank,
+                                m.qk_nope_head_dim, m.qk_rope_head_dim,
+                                m.v_head_dim)
+    elif mixer in ("attn", "local_attn"):
+        s["attn"] = L.gqa_specs(d, cfg.num_heads, cfg.num_kv_heads, hd,
+                                cfg.qkv_bias)
+    elif mixer == "kda":
+        k = cfg.kda
+        s["kda"] = L.kda_specs(d, k.num_heads, k.head_dim, k.conv_size,
+                               k.gate_rank)
+    elif mixer == "rglru":
         w = cfg.lru_width or d
         s["rglru"] = L.rglru_specs(d, w, cfg.num_heads, cfg.conv1d_width)
-        s["ln2"] = Spec((d,), ("embed",), "ones")
-        s["mlp"] = L.swiglu_specs(d, cfg.d_ff)
-    elif kind == "mlstm":
+    elif mixer == "mlstm":
         s["mlstm"] = L.mlstm_specs(d, cfg.num_heads)
-    elif kind == "slstm":
+    elif mixer == "slstm":
         s["slstm"] = L.slstm_specs(d, cfg.num_heads)
-        s["ln2"] = Spec((d,), ("embed",), "ones")
+    else:
+        raise ValueError(mixer)
+    if ffn == "none":
+        return s
+    s["ln2"] = Spec((d,), ("embed",), "ones")
+    if ffn == "moe":
+        s["moe"] = L.moe_specs(d, cfg.moe.num_experts, cfg.moe.d_expert,
+                               cfg.moe.num_shared)
+    elif mixer == "slstm":
         s["mlp"] = L.swiglu_specs(d, _slstm_ff_dim(d))
     else:
-        raise ValueError(kind)
+        ff = cfg.moe.dense_d_ff if cfg.moe else cfg.d_ff
+        s["mlp"] = L.swiglu_specs(d, ff)
     return s
 
 
-def block_apply_train(cfg: ArchConfig, kind: str, p: Params, x: jax.Array,
-                      rt: Runtime) -> jax.Array:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
+def _mla_kw(cfg: ArchConfig) -> Dict[str, Any]:
+    m = cfg.mla
+    return dict(n_heads=cfg.num_heads, kv_lora=m.kv_lora_rank,
+                nope=m.qk_nope_head_dim, rope_d=m.qk_rope_head_dim,
+                v_hd=m.v_head_dim, rope_theta=cfg.rope_theta,
+                eps=cfg.norm_eps, rope=m.use_rope)
+
+
+def _ffn(cfg: ArchConfig, p: Params, x: jax.Array,
+         rt: Runtime) -> jax.Array:
+    """The block's residual FFN on x (its second norm included)."""
+    h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+    if "moe" in p:
+        m = cfg.moe
+        y = L.moe_block(p["moe"], h2, n_experts=m.num_experts,
+                        top_k=m.top_k, normalize_gates=m.norm_topk_prob,
+                        rt=rt, scoring=m.scoring, scale=m.routed_scale)
+    else:
+        y = L.swiglu(p["mlp"], h2, rt)
+    return x + y
+
+
+def block_apply_train(cfg: ArchConfig, kind: Tuple[str, str], p: Params,
+                      x: jax.Array, rt: Runtime) -> jax.Array:
+    mixer, ffn = kind
+    hd = cfg.resolved_head_dim
     eps = cfg.norm_eps
     h = L.rms_norm(x, p["ln1"], eps)
-    if kind in ("attn", "attn_dense", "local_attn"):
-        window = cfg.local_window if kind == "local_attn" else 0
-        if cfg.mla is not None:
-            m = cfg.mla
-            a = L.mla_attention_train(
-                p["attn"], h, n_heads=cfg.num_heads,
-                kv_lora=m.kv_lora_rank, nope=m.qk_nope_head_dim,
-                rope_d=m.qk_rope_head_dim, v_hd=m.v_head_dim,
-                rope_theta=cfg.rope_theta, eps=eps, rt=rt)
-        else:
-            a = L.gqa_attention_train(
-                p["attn"], h, n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
-                hd=hd, rope_theta=cfg.rope_theta, rt=rt, causal=True,
-                window=window)
-        x = x + a
-        h2 = L.rms_norm(x, p["ln2"], eps)
-        if "moe" in p:
-            m = cfg.moe
-            y = L.moe_block(p["moe"], h2, n_experts=m.num_experts,
-                            top_k=m.top_k,
-                            normalize_gates=m.norm_topk_prob, rt=rt)
-        else:
-            y = L.swiglu(p["mlp"], h2, rt)
-        return x + y
-    if kind == "rglru":
+    if mixer == "mla":
+        a = L.mla_attention_train(p["attn"], h, rt=rt, **_mla_kw(cfg))
+    elif mixer in ("attn", "local_attn"):
+        window = cfg.local_window if mixer == "local_attn" else 0
+        a = L.gqa_attention_train(
+            p["attn"], h, n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
+            hd=hd, rope_theta=cfg.rope_theta, rt=rt, causal=True,
+            window=window)
+    elif mixer == "kda":
+        k = cfg.kda
+        a = L.kda_block_train(p["kda"], h, n_heads=k.num_heads,
+                              hd=k.head_dim, eps=eps, rt=rt)
+    elif mixer == "rglru":
         a = L.rglru_block_train(p["rglru"], h, n_heads=cfg.num_heads, rt=rt)
-        x = x + a
-        h2 = L.rms_norm(x, p["ln2"], eps)
-        return x + L.swiglu(p["mlp"], h2, rt)
-    if kind == "mlstm":
-        return x + L.mlstm_block_train(p["mlstm"], h, n_heads=cfg.num_heads,
-                                       eps=eps, rt=rt)
-    if kind == "slstm":
+    elif mixer == "mlstm":
+        a = L.mlstm_block_train(p["mlstm"], h, n_heads=cfg.num_heads,
+                                eps=eps, rt=rt)
+    elif mixer == "slstm":
         a = L.slstm_block_train(p["slstm"], h, n_heads=cfg.num_heads,
                                 eps=eps, rt=rt)
-        x = x + a
-        h2 = L.rms_norm(x, p["ln2"], eps)
-        return x + L.swiglu(p["mlp"], h2, rt)
-    raise ValueError(kind)
+    else:
+        raise ValueError(mixer)
+    x = x + a
+    return x if ffn == "none" else _ffn(cfg, p, x, rt)
 
 
-def block_cache_specs(cfg: ArchConfig, kind: str, batch: int,
+def block_cache_specs(cfg: ArchConfig, kind: Tuple[str, str], batch: int,
                       max_len: int) -> Dict[str, Any]:
+    """Per-layer decode state: a KV or latent cache (axis "kv_seq") for
+    attention, a fixed-size recurrent state for everything else."""
+    mixer, _ = kind
     d, hd = cfg.d_model, cfg.resolved_head_dim
-    if kind in ("attn", "attn_dense", "local_attn"):
-        if cfg.mla is not None:
-            m = cfg.mla
-            c: Dict[str, Any] = {
-                "ckv": Spec((batch, max_len, m.kv_lora_rank),
-                            ("batch", "kv_seq", None), "zeros", "bf16"),
-                "krope": Spec((batch, max_len, m.qk_rope_head_dim),
-                              ("batch", "kv_seq", None), "zeros", "bf16"),
-            }
-            return c
-        s_len = min(cfg.local_window, max_len) if kind == "local_attn" \
+    if mixer == "mla":
+        m = cfg.mla
+        return {
+            "ckv": Spec((batch, max_len, m.kv_lora_rank),
+                        ("batch", "kv_seq", None), "zeros", "bf16"),
+            "krope": Spec((batch, max_len, m.qk_rope_head_dim),
+                          ("batch", "kv_seq", None), "zeros", "bf16"),
+        }
+    if mixer in ("attn", "local_attn"):
+        s_len = min(cfg.local_window, max_len) if mixer == "local_attn" \
             else max_len
         return {
             "k": Spec((batch, s_len, cfg.num_kv_heads, hd),
@@ -186,14 +210,23 @@ def block_cache_specs(cfg: ArchConfig, kind: str, batch: int,
                       ("batch", "kv_seq", "kv_heads", None), "zeros",
                       "bf16"),
         }
-    if kind == "rglru":
+    if mixer == "kda":
+        k = cfg.kda
+        w = 3 * k.num_heads * k.head_dim
+        return {
+            "S": Spec((batch, k.num_heads, k.head_dim, k.head_dim),
+                      ("batch", None, None, None), "zeros", "f32"),
+            "conv": Spec((batch, k.conv_size - 1, w),
+                         ("batch", None, "qkv_fused"), "zeros", "f32"),
+        }
+    if mixer == "rglru":
         w = cfg.lru_width or d
         return {
             "h": Spec((batch, w), ("batch", "lru"), "zeros", "f32"),
             "conv": Spec((batch, cfg.conv1d_width - 1, w),
                          ("batch", None, "lru"), "zeros", "f32"),
         }
-    if kind == "mlstm":
+    if mixer == "mlstm":
         u = 2 * d
         uhd = u // cfg.num_heads
         return {
@@ -204,59 +237,46 @@ def block_cache_specs(cfg: ArchConfig, kind: str, batch: int,
             "m": Spec((batch, cfg.num_heads), ("batch", None), "zeros",
                       "f32"),
         }
-    if kind == "slstm":
+    if mixer == "slstm":
         return {k: Spec((batch, d), ("batch", None), "zeros", "f32")
                 for k in ("h", "c", "n", "m")}
-    raise ValueError(kind)
+    raise ValueError(mixer)
 
 
-def block_apply_decode(cfg: ArchConfig, kind: str, p: Params, x: jax.Array,
-                       cache: Dict[str, jax.Array], pos: jax.Array,
-                       rt: Runtime) -> Tuple[jax.Array, Dict]:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
+def block_apply_decode(cfg: ArchConfig, kind: Tuple[str, str], p: Params,
+                       x: jax.Array, cache: Dict[str, jax.Array],
+                       pos: jax.Array, rt: Runtime) -> Tuple[jax.Array, Dict]:
+    mixer, ffn = kind
+    hd = cfg.resolved_head_dim
     eps = cfg.norm_eps
     h = L.rms_norm(x, p["ln1"], eps)
-    if kind in ("attn", "attn_dense", "local_attn"):
-        window = cfg.local_window if kind == "local_attn" else 0
-        if cfg.mla is not None:
-            m = cfg.mla
-            a, cache = L.mla_attention_decode(
-                p["attn"], h, cache, pos, n_heads=cfg.num_heads,
-                kv_lora=m.kv_lora_rank, nope=m.qk_nope_head_dim,
-                rope_d=m.qk_rope_head_dim, v_hd=m.v_head_dim,
-                rope_theta=cfg.rope_theta, eps=eps, rt=rt)
-        else:
-            a, cache = L.gqa_attention_decode(
-                p["attn"], h, cache, pos, n_heads=cfg.num_heads,
-                n_kv=cfg.num_kv_heads, hd=hd, rope_theta=cfg.rope_theta,
-                rt=rt, window=window)
-        x = x + a
-        h2 = L.rms_norm(x, p["ln2"], eps)
-        if "moe" in p:
-            m = cfg.moe
-            y = L.moe_block(p["moe"], h2, n_experts=m.num_experts,
-                            top_k=m.top_k,
-                            normalize_gates=m.norm_topk_prob, rt=rt)
-        else:
-            y = L.swiglu(p["mlp"], h2, rt)
-        return x + y, cache
-    if kind == "rglru":
+    if mixer == "mla":
+        a, cache = L.mla_attention_decode(p["attn"], h, cache, pos, rt=rt,
+                                          **_mla_kw(cfg))
+    elif mixer in ("attn", "local_attn"):
+        window = cfg.local_window if mixer == "local_attn" else 0
+        a, cache = L.gqa_attention_decode(
+            p["attn"], h, cache, pos, n_heads=cfg.num_heads,
+            n_kv=cfg.num_kv_heads, hd=hd, rope_theta=cfg.rope_theta,
+            rt=rt, window=window)
+    elif mixer == "kda":
+        k = cfg.kda
+        a, cache = L.kda_block_decode(p["kda"], h, cache,
+                                      n_heads=k.num_heads, hd=k.head_dim,
+                                      eps=eps, rt=rt)
+    elif mixer == "rglru":
         a, cache = L.rglru_block_decode(p["rglru"], h, cache,
                                         n_heads=cfg.num_heads, rt=rt)
-        x = x + a
-        h2 = L.rms_norm(x, p["ln2"], eps)
-        return x + L.swiglu(p["mlp"], h2, rt), cache
-    if kind == "mlstm":
+    elif mixer == "mlstm":
         a, cache = L.mlstm_block_decode(p["mlstm"], h, cache,
                                         n_heads=cfg.num_heads, eps=eps, rt=rt)
-        return x + a, cache
-    if kind == "slstm":
+    elif mixer == "slstm":
         a, cache = L.slstm_block_decode(p["slstm"], h, cache,
                                         n_heads=cfg.num_heads, eps=eps, rt=rt)
-        x = x + a
-        h2 = L.rms_norm(x, p["ln2"], eps)
-        return x + L.swiglu(p["mlp"], h2, rt), cache
-    raise ValueError(kind)
+    else:
+        raise ValueError(mixer)
+    x = x + a
+    return (x if ffn == "none" else _ffn(cfg, p, x, rt)), cache
 
 
 # ================================================================= the model

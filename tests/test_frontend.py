@@ -1,8 +1,13 @@
 """jaxpr graph-capture frontend (repro.frontend): lowering parity against
 the hand-built graph DSL, sub-jaxpr handling, and the model-zoo workloads."""
 
+import hashlib
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax import lax
 
@@ -15,7 +20,7 @@ from repro.core.search import optimize_for_app
 from repro.core.space import default_space
 from repro.frontend import trace_to_graph
 from repro.frontend.zoo import DECODE_CACHE, PREFILL_SEQ
-from repro.models.layers import Runtime
+from repro.models.layers import KDA_CHUNK, Runtime
 
 
 def _op_sig(op):
@@ -219,6 +224,139 @@ def test_deepseek_prefill_costs_the_plain_count():
                and op.nix == 12]
     assert len(experts) == 3 * (a.num_layers - a.moe.first_dense)
     assert all(op.kind == OpKind.MATMUL for op in experts)
+
+
+def _kimi_plain_macs(variant: str) -> int:
+    """kimi-linear-48b-a3b's MACs from its layer equations.  KDA: the
+    projections, the kernel-4 depthwise conv and low-rank gates per token;
+    decode adds the state's three dk x dv products per head (k^T S, the
+    rank-1 write, S^T q), prefill the chunked form's per chunk and head
+    (C = 64, 16-token sub-chunks): the decayed pairs inside sub-chunks
+    (k.k and q.k, C*16*dk each), against earlier keys (C^2*dk each), the
+    two triangular products (C^2*dv, C^2*dk), the output's P w (C^2*dv),
+    and three C*dk*dv products with the state (w, output, state update).
+    MLA (no RoPE) as deepseek's: absorbed at decode, expanded with keys
+    padded to the 1024-key block at prefill.  MoE: router, top-8 routed
+    and one shared expert; layer 1 dense."""
+    a = configs.get_arch("kimi-linear-48b-a3b")
+    m, e, k = a.mla, a.moe, a.kda
+    d, h = a.d_model, a.num_heads
+    nope, rope_d, lora, vd = (m.qk_nope_head_dim, m.qk_rope_head_dim,
+                              m.kv_lora_rank, m.v_head_dim)
+    kh, dk, dv, r, C = (k.num_heads, k.head_dim, k.head_dim, k.gate_rank,
+                        KDA_CHUNK)
+    w = kh * dk
+    t = 1 if variant == "decode" else PREFILL_SEQ
+    kda = t * (d * 3 * w + k.conv_size * 3 * w       # W_qkv, short conv
+               + 2 * (d * r + r * w)                 # decay and out gates
+               + d * kh + w * d)                     # W_beta, W_o
+    proj = t * (d * h * (nope + rope_d) + d * (lora + rope_d) + h * vd * d)
+    if variant == "decode":
+        kda += kh * 3 * dk * dv
+        ctx = DECODE_CACHE
+        mla = proj + h * (nope * lora + ctx * (lora + rope_d) + ctx * lora
+                          + lora * vd)
+    else:
+        kda += -(-t // C) * kh * (2 * C * 16 * dk + 3 * C * C * dk
+                                  + 2 * C * C * dv + 3 * C * dk * dv)
+        keys = -(-t // Runtime().attn_kv_block) * Runtime().attn_kv_block
+        mla = (proj + t * lora * h * (nope + vd)
+               + h * t * keys * (nope + rope_d + vd))
+    moe = t * (d * e.num_experts + e.top_k * 3 * d * e.d_expert
+               + 3 * d * e.d_expert * e.num_shared)
+    n_kda = len(k.layers)
+    return (n_kda * kda + (a.num_layers - n_kda) * mla
+            + e.first_dense * t * 3 * d * e.dense_d_ff
+            + (a.num_layers - e.first_dense) * moe + d * a.vocab_size)
+
+
+@pytest.mark.parametrize("variant, macs", [("decode", 3_169_402_880),
+                                           ("prefill", 366_835_924_992)])
+def test_kimi_linear_costs_the_plain_count(variant, macs):
+    """Every KDA product that grows with the chunk or the state is a
+    costed op: the traced MACs are the layer equations' count (the
+    chunked form's are 7,717,519,360 of prefill's, 2.1%)."""
+    stream = apps.build_app(f"kimi-linear-48b-a3b:{variant}").op_stream()
+    assert stream.total_macs == _kimi_plain_macs(variant) == macs
+
+
+def test_short_conv_lowers_to_depthwise_conv():
+    """The KDA and RG-LRU short conv (`layers._causal_conv1d`): a grouped
+    `conv_general_dilated`, costed as Table 1's depthwise row, one filter
+    of K taps per channel over the left-padded sequence."""
+    from repro.models.layers import _causal_conv1d
+    x = jax.ShapeDtypeStruct((1, 16, 24), jnp.float32)
+    w = jax.ShapeDtypeStruct((4, 24), jnp.float32)
+    g = trace_to_graph(lambda w, x: _causal_conv1d(x, w), w, x, name="sc")
+    (node,) = _stream_nodes(g)
+    op = node.op
+    assert op.kind == OpKind.DEPTHWISE_CONV
+    assert (op.nif, op.nix, op.nkx, op.nof, op.nox, op.repeat) == \
+        (1, 16 + 3, 4, 1, 16, 24)
+    assert op.macs == 16 * 4 * 24
+    assert node.weight_bits == 4 * 24 * 8
+    stream = apps.build_app("kimi-linear-48b-a3b:decode").op_stream()
+    convs = [op for op in stream.ops if op.kind == OpKind.DEPTHWISE_CONV]
+    assert [(op.nix, op.nox, op.repeat) for op in convs] == \
+        [(4, 1, 3 * 32 * 128)] * 20
+
+
+def _alive_at_peak(graph):
+    """The vertices resident at the Fig. 5 peak (its replay)."""
+    remaining = {n: 0 for n in graph.nodes}
+    for node in graph.nodes.values():
+        for p in node.parents:
+            remaining[p] += 1
+    alive, best = {}, (-1, {})
+    for name in graph.operation_stream():
+        node = graph.nodes[name]
+        alive[name] = node.output_bits
+        if sum(alive.values()) > best[0]:
+            best = (sum(alive.values()), dict(alive))
+        for p in node.parents:
+            remaining[p] -= 1
+            if remaining[p] == 0:
+                alive.pop(p, None)
+        if remaining[name] == 0:
+            alive.pop(name, None)
+    return best
+
+
+def test_kimi_decode_floor_is_set_by_a_kda_state():
+    """One KDA state is [32, 128, 128] = 4,194,304 bits at 8 bits, over
+    seven times a layer's 128-slot latent cache (589,824), so decode's
+    Eq. 13 floor is KDA's: at the peak the largest resident vertices are
+    states."""
+    graph = apps.build_app("kimi-linear-48b-a3b:decode")
+    state = 32 * 128 * 128 * 8
+    spec = AppSpec.from_graph("kimi-linear-48b-a3b:decode", graph)
+    peak, alive = _alive_at_peak(graph)
+    assert spec.peak_input_bits == peak >= state
+    assert max(alive.values()) == state
+    assert sum(b for b in alive.values() if b == state) > peak // 2
+
+
+BENCH_CONFIGS = Path(__file__).resolve().parents[1] / "bench" / "configs"
+
+
+@pytest.mark.parametrize("config", ["qwen2.5-32b", "deepseek-v2-lite-16b",
+                                    "paper-cnn7"])
+def test_benchmark_streams_are_unchanged(config):
+    """The op streams the benchmark's configurations record (op count, a
+    sha256 of the [11, ops] op table, Eq. 11/13 peaks) are the traced
+    ones: the per-layer mixer/ffn plan, the sigmoid router, MLA without
+    RoPE and the depthwise short conv leave them byte-identical."""
+    conf = json.loads((BENCH_CONFIGS / f"{config}.json").read_text())
+    for app in conf["apps"]:
+        spec = AppSpec.from_app(app,
+                                weight_peak_mode=conf["weight_peak_mode"])
+        table = np.ascontiguousarray(spec.stream.field_matrix)
+        assert {"ops": table.shape[1],
+                "op_table_sha256": hashlib.sha256(table.tobytes()
+                                                  ).hexdigest(),
+                "peak_weight_bits": spec.peak_weight_bits,
+                "peak_input_bits": spec.peak_input_bits} == \
+            conf["streams"][app], app
 
 
 def test_scan_pjit_remat_are_traversed():

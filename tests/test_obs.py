@@ -255,6 +255,39 @@ def test_frontend_trace_and_scorer_width_are_counted():
     assert obs.metrics().counters["scorer.cells"] == 256 * columns
 
 
+def test_app_spec_span_and_state_bits_counter():
+    """A `Study` records one `study.app_specs` span around building its
+    apps' specs (args: the app count and their graphs' vertices), and
+    tracing a decode app that carries recurrent state counts its bits,
+    `frontend.state_bits`: 20 KDA layers of a [32, 128, 128] state and a
+    3 x 12288 conv history at 8 bits; an app with only KV or latent caches
+    counts none.  Disabled, nothing is recorded, and the result is the
+    same."""
+    from repro.core.apps import build_app
+    from repro.frontend.zoo import build_zoo_app
+    kw = dict(apps=["ptb", "wdl"], engine="random", backend="numpy",
+              budget=SearchBudget(restarts=1, max_rounds=2,
+                                  engine_kwargs={"batch": 16}), seed=0)
+    plain = result_bytes(Study(**kw).run())
+    assert len(obs.tracer()) == 0
+    obs.enable(trace=True, metrics=True, journal=False)
+    assert result_bytes(Study(**kw).run()) == plain
+    spans = [e for e in obs.tracer().export()
+             if e.get("name") == "study.app_specs"]
+    assert [e["args"] for e in spans] == [{
+        "apps": 2,
+        "nodes": len(build_app("ptb").nodes) + len(build_app("wdl").nodes)}]
+
+    build_zoo_app.cache_clear()
+    build_zoo_app("kimi-linear-48b-a3b:decode")
+    assert obs.metrics().counters["frontend.state_bits"] == \
+        20 * (32 * 128 * 128 + 3 * 3 * 32 * 128) * 8
+    build_zoo_app("deepseek-v2-lite-16b:decode")
+    build_zoo_app("kimi-linear-48b-a3b:prefill")
+    assert obs.metrics().counters["frontend.state_bits"] == \
+        89_784_320
+
+
 def test_peak_repair_span_and_counters():
     """A batched peak repair records one `space.repair` span (its row
     count) and counts the rows in, the rows the buffer growth moved and
